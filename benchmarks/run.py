@@ -187,6 +187,8 @@ def main(argv=None) -> None:
     args = ap.parse_args(argv)
     if args.fail_on_regress is not None and not args.diff:
         ap.error("--fail-on-regress requires --diff BASELINE.json")
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from benchmarks import (fpga_roofline, kernel_bench, lut_cost, lut_init,
                             qat_accuracy, resource_breakdown, serving_bench,

@@ -25,35 +25,18 @@ import jax
 import jax.numpy as jnp
 import msgpack
 import numpy as np
-
-import zlib
-
-try:
-    import zstandard
-    _HAVE_ZSTD = True
-except ImportError:                   # gate: container without zstd bindings
-    zstandard = None
-    _HAVE_ZSTD = False
+import zstandard
 
 
 def _compress(payload: bytes) -> tuple[bytes, str]:
-    """Returns (bytes, codec); codec is recorded in the manifest so restore
-    never has to guess the frame format."""
-    if _HAVE_ZSTD:
-        return zstandard.ZstdCompressor(level=3).compress(payload), "zstd"
-    return zlib.compress(payload, 3), "zlib"
+    """Returns (bytes, codec); codec is recorded in the manifest."""
+    return zstandard.ZstdCompressor(level=3).compress(payload), "zstd"
 
 
 def _decompress(data: bytes, codec: str) -> bytes:
-    if codec == "zlib":
-        return zlib.decompress(data)
-    if codec == "zstd":
-        if not _HAVE_ZSTD:
-            raise RuntimeError(
-                "checkpoint was written with zstd but the zstandard module "
-                "is not installed in this environment")
-        return zstandard.ZstdDecompressor().decompress(data)
-    raise ValueError(f"unknown checkpoint codec {codec!r}")
+    if codec != "zstd":
+        raise ValueError(f"unknown checkpoint codec {codec!r}")
+    return zstandard.ZstdDecompressor().decompress(data)
 
 
 def _flatten_with_paths(tree):

@@ -13,7 +13,7 @@ import contextlib
 from typing import Optional
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 
 class Rules(dict):
@@ -43,13 +43,10 @@ def production_rules(multi_pod: bool = False) -> Rules:
 
 
 def make_mesh(axis_shapes, axis_names) -> Mesh:
-    """``jax.make_mesh`` with Auto axis types when the installed jax has
-    them (>= 0.5); plain mesh otherwise — call sites stay version-agnostic."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(axis_shapes, axis_names,
-                             axis_types=(axis_type.Auto,) * len(axis_names))
-    return jax.make_mesh(axis_shapes, axis_names)
+    """``jax.make_mesh`` with every axis ``Auto`` (the sharding rules and
+    ``constrain`` call sites leave placement to the compiler)."""
+    return jax.make_mesh(axis_shapes, axis_names,
+                         axis_types=(AxisType.Auto,) * len(axis_names))
 
 
 # -- active-rules context ----------------------------------------------------

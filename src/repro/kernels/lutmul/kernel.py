@@ -7,20 +7,20 @@ activations) and ``w[k,n]`` (4-bit weights) the accumulator is
 
     acc[m,n] = sum_k T[w[k,n], a[m,k]]
              = sum_{k,b} bit_b(a[m,k]) * TW[(k,b), n]            (b = 0..3)
-    TW[(k,b), n] = sum_{w'} onehot(w[k,n]==w') * T[w', 2^b]
+    TW[(k,b), n] = T[w[k,n], 2^b]          (a lookup: one-hot in w[k,n])
 
-i.e. two ``dot_general`` calls per block: one-hot weight codes select their
-four power-of-two partial products ``T[w, 2^b]`` from the product table (a
-[bk*bn, 16] x [16, 4] dot — the activation-code-8 column carries the top
+Per block, each weight code selects its four power-of-two partial products
+``T[w, 2^b]`` from the product table with a 4-level select tree on its
+bits (the table sits in SMEM; the activation-code-8 column carries the top
 bit's sign, so signed vs unsigned activations is purely a table-layout
-choice), then bitplaned activation nibbles select-and-reduce over K (a
-[bm, bk*4] x [bk*4, bn] dot with int32 accumulation).  Multiplication is
-still performed by *selection from the product table* — the faithful LUT
-semantics — but the selection is a contraction the MXU executes natively:
-on TPU both dots are int8 (every operand value fits int8).  The MAC count is
-4x an int8 matmul (the price of selection); the serial per-row gather loop
-it replaces is ~5-8x slower even in interpret mode and far worse on real
-hardware.
+choice), then bitplaned activation nibbles select-and-reduce over K on the
+MXU (a [bm, 4*bk] x [4*bk, bn] dot with int32 accumulation).
+Multiplication is still performed by *selection from the product table* —
+the faithful LUT semantics: every partial product is a table entry, never
+computed.  On TPU the dot is int8 (every operand value fits int8); its MAC
+count is 4x an int8 matmul (the price of selection), and the selection
+costs 15 selects per weight code and bit on the VPU, independent of M.
+The serial per-row gather loop is kept as ``lutmul_gather``.
 
 ``lutmul_tmac``: the second formulation — T-MAC/BitNet-style *weight-plane*
 decomposition against *activation-group* partial-sum tables.  Weights are
@@ -32,7 +32,9 @@ precomputes the partial-sum table
     T[m, kg, c] = sum_{i<g} bit_i(c) * a[m, kg*g + i]       (c = 0..2^g-1)
 
 (the T-MAC ``LUT[n, k, Abits]`` table, built in-VMEM per block with one
-tiny [bm*K/g, g] x [g, 2^g] dot — N-independent).  Each weight plane's
+lane-aligned [bm, bk] x [bk, bk/g * 2^g] dot against a constant 0/1 group
+matrix — N-independent, and free of the sub-tile reshapes the TPU compiler
+refuses).  Each weight plane's
 g-bit group codes then *select* from T via a one-hot contraction and the
 coefficients fold into the one-hot operand, so the whole thing is ONE
 ``[bm, P * K/g * 2^g] x [P * K/g * 2^g, bn]`` MXU dot:
@@ -79,6 +81,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -95,50 +98,62 @@ def _unpack_codes(wp: jax.Array) -> jax.Array:
     return jnp.stack([w_lo, w_hi], axis=1).reshape(-1, wp.shape[1])
 
 
-def _onehot_contract(a: jax.Array, wp: jax.Array, t2: jax.Array,
-                     contract_dtype=jnp.float32) -> jax.Array:
-    """One block of the one-hot/bitplane LUT contraction (module docstring).
+def _select_tree(bits, leaves):
+    """``leaves[code]`` for every element: a binary select tree on the
+    code's bits (``bits[j]`` = bit j is set), lowest bit first."""
+    for bit in bits:
+        leaves = [jnp.where(bit, leaves[2 * i + 1], leaves[2 * i])
+                  for i in range(len(leaves) // 2)]
+    return leaves[0]
 
-    a: [bm, bk] int32 codes; wp: [bk//2, bn] packed codes; t2: [16, 16] int32
-    product table (row = weight code, col = activation code).  Returns the
+
+def _onehot_contract(a: jax.Array, wp: jax.Array, t2,
+                     contract_dtype) -> jax.Array:
+    """One block of the LUT contraction (module docstring).
+
+    a: [bm, bk] int32 codes; wp: [bk//2, bn] packed codes (byte k2 holds
+    w[2*k2] in its low nibble, w[2*k2+1] in its high one); t2: [16, 16]
+    int32 product table (row = weight code, col = activation code) — an
+    SMEM ref in the kernel, any indexable array elsewhere.  Returns the
     int32 [bm, bn] partial accumulator.
 
-    ``contract_dtype``: int8 on the TPU path (both dots are MXU-native int8
-    with int32 accumulation — every value involved fits int8); float32 in
+    ``contract_dtype``: int8 on the TPU path (an MXU-native int8 dot with
+    int32 accumulation — every value involved fits int8); float32 in
     interpret mode, where XLA:CPU has no fast int8 GEMM.  f32 accumulation is
     exact here: per-block partial sums are bounded by bk * 64 << 2^24.
     """
-    bm, bk = a.shape
-    w = _unpack_codes(wp.astype(jnp.int32))                    # [bk, bn]
-    bn = w.shape[1]
-    # selection stage: one-hot weight codes pick their 4 power-of-two partial
-    # products T[w, 2^b] from the product table (T[w, 8] carries the sign of
-    # the activation top bit: -8w for signed codes, +8w for unsigned — the
-    # table layout, not the kernel, decides the signedness)
-    cols = jnp.stack([t2[:, 1], t2[:, 2], t2[:, 4], t2[:, 8]],
-                     axis=1).astype(contract_dtype)            # [16, 4]
-    codes = jax.lax.broadcasted_iota(jnp.int32, (1, 1, 16), 2)
-    w_oh = (w[:, :, None] == codes).reshape(bk * bn, 16).astype(contract_dtype)
-    tw = jax.lax.dot_general(
-        w_oh, cols, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32
-        if contract_dtype == jnp.float32 else jnp.int32)       # [bk*bn, 4]
-    tw = tw.astype(contract_dtype).reshape(
-        bk, bn, 4).transpose(0, 2, 1).reshape(bk * 4, bn)
-    # accumulation stage: bitplane the activation nibbles and contract —
-    # the MXU only ever selects and sums table entries, never multiplies
-    shifts = jax.lax.broadcasted_iota(jnp.int32, (1, 1, 4), 2)
-    a_bits = ((a[:, :, None] >> shifts) & 1).reshape(
-        bm, bk * 4).astype(contract_dtype)
+    bk = a.shape[1]
+    pref = jnp.float32 if contract_dtype == jnp.float32 else jnp.int32
+    wp = wp.astype(jnp.int32)
+    # selection stage, on the two nibble halves (even k, odd k) so no
+    # sublane interleave is needed: TW rows are ordered (b, parity, k2)
+    halves = [[(w >> j) & 1 == 1 for j in range(4)]
+              for w in (wp & 0xF, wp >> 4)]
+    tw = jnp.concatenate(
+        [_select_tree(bits, [t2[c, 1 << b] for c in range(16)])
+         for b in range(4) for bits in halves],
+        axis=0).astype(contract_dtype)                          # [4*bk, bn]
+    # accumulation stage: activation codes reordered to (parity, k2) by an
+    # exact 0/1 permutation dot, bitplaned b-major to match TW's rows —
+    # the MXU only ever selects and sums table entries
+    k = jax.lax.broadcasted_iota(jnp.int32, (bk, bk), 0)
+    j = jax.lax.broadcasted_iota(jnp.int32, (bk, bk), 1)
+    half = bk // 2
+    perm = (k == jnp.where(j < half, 2 * j, 2 * (j - half) + 1))
+    a = jax.lax.dot_general(
+        a.astype(contract_dtype), perm.astype(contract_dtype),
+        (((1,), (0,)), ((), ())),
+        preferred_element_type=pref).astype(jnp.int32)          # [bm, bk]
+    a_bits = jnp.concatenate([(a >> b) & 1 for b in range(4)],
+                             axis=1).astype(contract_dtype)     # [bm, 4*bk]
     acc = jax.lax.dot_general(
         a_bits, tw, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32
-        if contract_dtype == jnp.float32 else jnp.int32)       # [bm, bn]
+        preferred_element_type=pref)                            # [bm, bn]
     return acc.astype(jnp.int32)
 
 
 def _lutmul_onehot_body(a_ref, w_ref, t_ref, out_ref, *,
-                        contract_dtype=jnp.float32):
+                        contract_dtype):
     """Grid: (M/bm, N/bn, K/bk); K is the innermost ('arbitrary') dimension."""
     k = pl.program_id(2)
 
@@ -147,7 +162,7 @@ def _lutmul_onehot_body(a_ref, w_ref, t_ref, out_ref, *,
         out_ref[...] = jnp.zeros_like(out_ref)
 
     out_ref[...] += _onehot_contract(a_ref[...].astype(jnp.int32),
-                                     w_ref[...], t_ref[...], contract_dtype)
+                                     w_ref[...], t_ref, contract_dtype)
 
 
 def _lutmul_gather_body(a_ref, w_ref, t_ref, out_ref, *, unroll: int = 8):
@@ -178,7 +193,7 @@ def _lutmul_gather_body(a_ref, w_ref, t_ref, out_ref, *, unroll: int = 8):
 def lutmul_pallas(a_codes: jax.Array, w_packed: jax.Array, table: jax.Array,
                   *, bm: int = DEFAULT_BM, bn: int = DEFAULT_BN,
                   bk: int = DEFAULT_BK, impl: str = "onehot",
-                  interpret: bool = True) -> jax.Array:
+                  interpret: bool) -> jax.Array:
     """a_codes: [M, K] uint8; w_packed: [K//2, N] uint8; table: [16, 16] int32.
 
     Shapes must be pre-padded to block multiples (ops.py handles padding).
@@ -196,7 +211,7 @@ def lutmul_pallas(a_codes: jax.Array, w_packed: jax.Array, table: jax.Array,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
             pl.BlockSpec((bk // 2, bn), lambda i, j, k: (k, j)),
-            pl.BlockSpec((16, 16), lambda i, j, k: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),      # product table
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.int32),
@@ -210,23 +225,25 @@ def lutmul_pallas(a_codes: jax.Array, w_packed: jax.Array, table: jax.Array,
 # ---------------------------------------------------------------------------
 
 
-def _tmac_contract(a: jax.Array, wp: jax.Array, coeffs: tuple[int, ...],
-                   g: int, contract_dtype=jnp.float32) -> jax.Array:
+def _tmac_contract(a: jax.Array, wp: jax.Array, gmat: jax.Array | None,
+                   coeffs: tuple[int, ...], g: int,
+                   contract_dtype) -> jax.Array:
     """One block of the tmac contraction (WITHOUT the const correction).
 
     a: [bm, bk] int32 signed activation codes; wp: [P, bk//8, bn] packed
-    bitplanes; coeffs: static per-plane integer coefficients.  Returns the
+    bitplanes; gmat: the ``tmac_group_matrix(bk, g)`` block (None for
+    g=1); coeffs: static per-plane integer coefficients.  Returns the
     int32 [bm, bn] partial accumulator ``sum_b coeff_b * (a . plane_b)``.
     """
     n_planes = wp.shape[0]
-    bm, bk = a.shape
+    bk = a.shape[1]
     bn = wp.shape[-1]
-    # unpack bitplanes: [P, bk//8, bn] bytes -> [P, bk, bn] {0, 1}
-    shifts = jax.lax.broadcasted_iota(jnp.int32, (1, 1, 8, 1), 2)
-    w = ((wp.astype(jnp.int32)[:, :, None, :] >> shifts) & 1) \
-        .reshape(n_planes, bk, bn)
     pref = jnp.float32 if contract_dtype == jnp.float32 else jnp.int32
     if g == 1:
+        # unpack bitplanes: [P, bk//8, bn] bytes -> [P, bk, bn] {0, 1}
+        shifts = jax.lax.broadcasted_iota(jnp.int32, (1, 1, 8, 1), 2)
+        w = ((wp.astype(jnp.int32)[:, :, None, :] >> shifts) & 1) \
+            .reshape(n_planes, bk, bn)
         # degenerate table T[m, k, {0,1}] = {0, a}: contract the
         # coefficient-scaled planes directly (inner dim P * bk)
         ws = jnp.concatenate(
@@ -237,82 +254,112 @@ def _tmac_contract(a: jax.Array, wp: jax.Array, coeffs: tuple[int, ...],
         acc = jax.lax.dot_general(at, ws, (((1,), (0,)), ((), ())),
                                   preferred_element_type=pref)
         return acc.astype(jnp.int32)
-    kg, c = bk // g, 1 << g
-    # table stage: T[m, kg, c] = sum_i bit_i(c) * a[m, kg*g+i] — one tiny
-    # N-independent dot builds every group's 2^g partial sums
-    bitsel = ((jax.lax.broadcasted_iota(jnp.int32, (g, c), 1)
-               >> jax.lax.broadcasted_iota(jnp.int32, (g, c), 0)) & 1)
+    # g >= 2.  Group kg = r * (8/g) + j covers bits [g*j, g*j + g) of
+    # packed byte row r, so every group code is a shift-and-mask of the
+    # bytes and no sublane reshape is needed.  Table columns and selection
+    # rows are both ordered (j, c, r), r fastest.
+    c, per = 1 << g, 8 // g
+    # table stage: T[m, (j, c, r)] = sum_i bit_i(c) * a[m, 8r + g*j + i] —
+    # one lane-aligned dot against the constant 0/1 group matrix
     table = jax.lax.dot_general(
-        a.reshape(bm * kg, g).astype(contract_dtype),
-        bitsel.astype(contract_dtype), (((1,), (0,)), ((), ())),
-        preferred_element_type=pref)                            # [bm*kg, c]
-    table = table.astype(contract_dtype).reshape(bm, kg * c)
+        a.astype(contract_dtype), gmat.astype(contract_dtype),
+        (((1,), (0,)), ((), ())), preferred_element_type=pref)
+    table = table.astype(contract_dtype)                  # [bm, per*c*bk/8]
     # selection stage: per-plane g-bit group codes one-hot against the
     # table, coefficients folded into the one-hot operand -> ONE dot
-    gsh = jax.lax.broadcasted_iota(jnp.int32, (1, 1, g, 1), 2)
-    gcodes = jnp.sum(w.reshape(n_planes, kg, g, bn) << gsh,
-                     axis=2)                                    # [P, kg, bn]
-    codes = jax.lax.broadcasted_iota(jnp.int32, (1, c, 1), 1)
+    wp = wp.astype(jnp.int32)
     sel = jnp.concatenate(
-        [(gcodes[p][:, None, :] == codes).astype(jnp.int32) * coeffs[p]
-         for p in range(n_planes)],
-        axis=0).astype(contract_dtype).reshape(n_planes * kg * c, bn)
-    at = jnp.concatenate([table] * n_planes, axis=1)            # plane-major
+        [(((wp[p] >> (g * j)) & (c - 1)) == code).astype(jnp.int32)
+         * coeffs[p]
+         for p in range(n_planes) for j in range(per) for code in range(c)],
+        axis=0).astype(contract_dtype)                    # [P*per*c*bk/8, bn]
+    at = jnp.concatenate([table] * n_planes, axis=1)      # plane-major
     acc = jax.lax.dot_general(at, sel, (((1,), (0,)), ((), ())),
                               preferred_element_type=pref)
     return acc.astype(jnp.int32)
 
 
-def _tmac_block(a_ref, w_ref, *, coeffs, const, g, contract_dtype):
+def tmac_group_matrix(bk: int, g: int) -> np.ndarray:
+    """[bk, (8/g) * 2^g * bk/8] int8 0/1 matrix whose product with a block of
+    activations is the T-MAC partial-sum table in ``_tmac_contract``'s
+    (j, c, r) column order: column (j, c, r) sums the activations at
+    k = 8r + g*j + i over the bits i set in code c."""
+    c, per, rows = 1 << g, 8 // g, bk // 8
+    k = np.arange(bk)[:, None]
+    col = np.arange(per * c * rows)[None, :]
+    j, code, r = col // (c * rows), (col // rows) % c, col % rows
+    i = k - 8 * r - g * j
+    hit = (i >= 0) & (i < g) & ((code >> np.clip(i, 0, g - 1)) & 1 == 1)
+    return hit.astype(np.int8)
+
+
+def _tmac_operands(g: int, bk: int):
+    """The constant group-matrix operand and its BlockSpec (none for g=1).
+    Its block index never changes, so it is copied into VMEM once."""
+    if g == 1:
+        return [], []
+    gm = jnp.asarray(tmac_group_matrix(bk, g))
+    return [gm], [pl.BlockSpec(gm.shape, lambda i, j, k: (0, 0))]
+
+
+def _tmac_block(a_ref, w_ref, g_refs, *, coeffs, const, g, contract_dtype):
     """Shared block body: tmac contraction + the binary-coding const
     correction (``const * sum_k a[m, k]``, exact per K block since padded
     activation codes are zero)."""
     a = a_ref[...].astype(jnp.int32)
-    acc = _tmac_contract(a, w_ref[...], coeffs, g, contract_dtype)
+    gmat = g_refs[0][...] if g_refs else None
+    acc = _tmac_contract(a, w_ref[...], gmat, coeffs, g, contract_dtype)
     if const:
         acc = acc + const * jnp.sum(a, axis=1, keepdims=True)
     return acc
 
 
-def _lutmul_tmac_body(a_ref, w_ref, out_ref, *, coeffs, const, g,
-                      contract_dtype=jnp.float32):
+def _lutmul_tmac_body(*refs, coeffs, const, g, contract_dtype):
+    a_ref, w_ref, *g_refs, out_ref = refs
     k = pl.program_id(2)
 
     @pl.when(k == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    out_ref[...] += _tmac_block(a_ref, w_ref, coeffs=coeffs, const=const,
-                                g=g, contract_dtype=contract_dtype)
+    out_ref[...] += _tmac_block(a_ref, w_ref, g_refs, coeffs=coeffs,
+                                const=const, g=g,
+                                contract_dtype=contract_dtype)
+
+
+def _check_tmac_block(bk: int, g: int) -> None:
+    if g not in (1, 2, 4) or bk % (8 * g):
+        raise ValueError(f"tmac needs g in (1, 2, 4) and bk % (8*g) == 0, "
+                         f"got bk={bk} g={g}")
 
 
 def lutmul_tmac_pallas(a_q: jax.Array, w_planes: jax.Array, *,
                        coeffs: tuple[int, ...], const: int = 0, g: int = 2,
                        bm: int = DEFAULT_BM, bn: int = DEFAULT_BN,
-                       bk: int = DEFAULT_BK,
-                       interpret: bool = True) -> jax.Array:
+                       bk: int = DEFAULT_BK, interpret: bool) -> jax.Array:
     """a_q: [M, K] int8 signed activation codes; w_planes: [P, K//8, N]
     packed bitplanes (core.lut.pack_bitplanes layout).  Shapes pre-padded to
     block multiples (ops.py pads); ``bk % (8 * g) == 0`` required."""
     M, K = a_q.shape
     n_planes, _, N = w_planes.shape
-    if bk % (8 * max(g, 1)):
-        raise ValueError(f"tmac needs bk % (8*g) == 0, got bk={bk} g={g}")
+    _check_tmac_block(bk, g)
     grid = (M // bm, N // bn, K // bk)
     cd = jnp.float32 if interpret else jnp.int8
     body = functools.partial(_lutmul_tmac_body, coeffs=tuple(coeffs),
                              const=const, g=g, contract_dtype=cd)
+    g_ops, g_specs = _tmac_operands(g, bk)
     return pl.pallas_call(
         body,
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
             pl.BlockSpec((n_planes, bk // 8, bn), lambda i, j, k: (0, k, j)),
+            *g_specs,
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.int32),
         interpret=interpret,
-    )(a_q, w_planes)
+    )(a_q, w_planes, *g_ops)
 
 
 def _int_matmul_body(a_ref, w_ref, out_ref):
@@ -331,7 +378,7 @@ def _int_matmul_body(a_ref, w_ref, out_ref):
 
 def int_matmul_pallas(a: jax.Array, w: jax.Array, *, bm: int = DEFAULT_BM,
                       bn: int = DEFAULT_BN, bk: int = DEFAULT_BK,
-                      interpret: bool = True) -> jax.Array:
+                      interpret: bool) -> jax.Array:
     """a: [M, K] int8; w: [K, N] int8 -> int32 [M, N]."""
     M, K = a.shape
     N = w.shape[1]
@@ -361,7 +408,7 @@ def _epilogue(acc, as_blk, ws_blk, out_dtype):
 
 
 def _lutmul_fused_body(a_ref, w_ref, t_ref, as_ref, ws_ref, out_ref, acc_ref,
-                       *, nk: int, out_dtype, contract_dtype=jnp.float32):
+                       *, nk: int, out_dtype, contract_dtype):
     k = pl.program_id(2)
 
     @pl.when(k == 0)
@@ -369,7 +416,7 @@ def _lutmul_fused_body(a_ref, w_ref, t_ref, as_ref, ws_ref, out_ref, acc_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     acc_ref[...] += _onehot_contract(a_ref[...].astype(jnp.int32),
-                                     w_ref[...], t_ref[...], contract_dtype)
+                                     w_ref[...], t_ref, contract_dtype)
 
     @pl.when(k == nk - 1)
     def _finish():
@@ -382,7 +429,7 @@ def lutmul_fused_pallas(a_codes: jax.Array, w_packed: jax.Array,
                         w_scale: jax.Array, *, bm: int = DEFAULT_BM,
                         bn: int = DEFAULT_BN, bk: int = DEFAULT_BK,
                         out_dtype=jnp.bfloat16,
-                        interpret: bool = True) -> jax.Array:
+                        interpret: bool) -> jax.Array:
     """One-hot LUT matmul + fused dequant.  a_scale: [M, 1] f32 per-token,
     w_scale: [1, N] f32 per-channel; returns [M, N] ``out_dtype``."""
     M, K = a_codes.shape
@@ -398,7 +445,7 @@ def lutmul_fused_pallas(a_codes: jax.Array, w_packed: jax.Array,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
             pl.BlockSpec((bk // 2, bn), lambda i, j, k: (k, j)),
-            pl.BlockSpec((16, 16), lambda i, j, k: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),      # product table
             pl.BlockSpec((bm, 1), lambda i, j, k: (i, 0)),
             pl.BlockSpec((1, bn), lambda i, j, k: (0, j)),
         ],
@@ -409,17 +456,18 @@ def lutmul_fused_pallas(a_codes: jax.Array, w_packed: jax.Array,
     )(a_codes, w_packed, table, a_scale, w_scale)
 
 
-def _lutmul_tmac_fused_body(a_ref, w_ref, as_ref, ws_ref, out_ref, acc_ref,
-                            *, nk: int, out_dtype, coeffs, const, g,
-                            contract_dtype=jnp.float32):
+def _lutmul_tmac_fused_body(*refs, nk: int, out_dtype, coeffs, const, g,
+                            contract_dtype):
+    a_ref, w_ref, *g_refs, as_ref, ws_ref, out_ref, acc_ref = refs
     k = pl.program_id(2)
 
     @pl.when(k == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += _tmac_block(a_ref, w_ref, coeffs=coeffs, const=const,
-                                g=g, contract_dtype=contract_dtype)
+    acc_ref[...] += _tmac_block(a_ref, w_ref, g_refs, coeffs=coeffs,
+                                const=const, g=g,
+                                contract_dtype=contract_dtype)
 
     @pl.when(k == nk - 1)
     def _finish():
@@ -433,12 +481,11 @@ def lutmul_tmac_fused_pallas(a_q: jax.Array, w_planes: jax.Array,
                              g: int = 2, bm: int = DEFAULT_BM,
                              bn: int = DEFAULT_BN, bk: int = DEFAULT_BK,
                              out_dtype=jnp.bfloat16,
-                             interpret: bool = True) -> jax.Array:
+                             interpret: bool) -> jax.Array:
     """T-MAC LUT matmul + fused dequant epilogue (see lutmul_tmac_pallas)."""
     M, K = a_q.shape
     n_planes, _, N = w_planes.shape
-    if bk % (8 * max(g, 1)):
-        raise ValueError(f"tmac needs bk % (8*g) == 0, got bk={bk} g={g}")
+    _check_tmac_block(bk, g)
     nk = K // bk
     grid = (M // bm, N // bn, nk)
     body = functools.partial(_lutmul_tmac_fused_body, nk=nk,
@@ -446,12 +493,14 @@ def lutmul_tmac_fused_pallas(a_q: jax.Array, w_planes: jax.Array,
                              const=const, g=g,
                              contract_dtype=jnp.float32 if interpret
                              else jnp.int8)
+    g_ops, g_specs = _tmac_operands(g, bk)
     return pl.pallas_call(
         body,
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
             pl.BlockSpec((n_planes, bk // 8, bn), lambda i, j, k: (0, k, j)),
+            *g_specs,
             pl.BlockSpec((bm, 1), lambda i, j, k: (i, 0)),
             pl.BlockSpec((1, bn), lambda i, j, k: (0, j)),
         ],
@@ -459,7 +508,7 @@ def lutmul_tmac_fused_pallas(a_q: jax.Array, w_planes: jax.Array,
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
         interpret=interpret,
-    )(a_q, w_planes, a_scale, w_scale)
+    )(a_q, w_planes, *g_ops, a_scale, w_scale)
 
 
 def _int_matmul_fused_body(a_ref, w_ref, as_ref, ws_ref, out_ref, acc_ref,
@@ -484,7 +533,7 @@ def int_matmul_fused_pallas(a: jax.Array, w: jax.Array, a_scale: jax.Array,
                             w_scale: jax.Array, *, bm: int = DEFAULT_BM,
                             bn: int = DEFAULT_BN, bk: int = DEFAULT_BK,
                             out_dtype=jnp.bfloat16,
-                            interpret: bool = True) -> jax.Array:
+                            interpret: bool) -> jax.Array:
     """int8 matmul + fused dequant (w4a4_mxu / w8a8 serving path)."""
     M, K = a.shape
     N = w.shape[1]

@@ -48,13 +48,30 @@ def set_backend(name: Optional[str]) -> None:
     _BACKEND = name
 
 
+_BACKENDS = ("pallas", "interpret", "ref")
+
+
 def get_backend() -> str:
+    """The kernel backend: ``set_backend`` wins, then REPRO_KERNEL_BACKEND,
+    then the platform default.  On a TPU the env var may not swap the
+    kernels for ``interpret`` or ``ref`` outside a test — a stray setting
+    would otherwise serve and time the oracle in place of the kernels."""
     if _BACKEND is not None:
         return _BACKEND
+    on_tpu = jax.default_backend() == "tpu"
     env = os.environ.get("REPRO_KERNEL_BACKEND")
     if env:
+        if env not in _BACKENDS:
+            raise ValueError(f"REPRO_KERNEL_BACKEND={env!r}: expected one "
+                             f"of {_BACKENDS}")
+        if (on_tpu and env != "pallas"
+                and "PYTEST_CURRENT_TEST" not in os.environ):
+            raise RuntimeError(
+                f"REPRO_KERNEL_BACKEND={env!r} on a TPU: the Pallas kernels "
+                "would be replaced by the CPU-test path.  Unset it, or call "
+                "ops.set_backend() where a comparison needs the oracle")
         return env
-    return "pallas" if jax.default_backend() == "tpu" else "ref"
+    return "pallas" if on_tpu else "ref"
 
 
 def _pad_to(x: jax.Array, m0: int, m1: int, value=0) -> jax.Array:
@@ -89,9 +106,11 @@ _AUTOTUNE: Optional[bool] = None
 _BLOCK_CACHE: dict[tuple, tuple[int, int, int]] = {}
 
 # (bm, bn, bk) candidates, all (8, 128, 128)-aligned; the first entry is the
-# heuristic default so a disabled autotuner is a zero-cost lookup
-_CANDIDATES = ((128, 128, 128), (256, 256, 256), (256, 128, 128),
-               (128, 256, 128), (64, 128, 128))
+# heuristic default so a disabled autotuner is a zero-cost lookup.  Wide
+# N/K blocks keep the grid short: at decode (M=8) a v5e pays a fixed cost
+# per grid step that dominated every kernel at 128x128 blocks.
+_CANDIDATES = ((128, 512, 512), (128, 128, 128), (256, 256, 256),
+               (128, 256, 256), (128, 512, 256))
 
 
 def set_autotune(enabled: Optional[bool]) -> None:
@@ -107,11 +126,20 @@ def autotune_enabled() -> bool:
 
 def _clip_blocks(M: int, K: int, N: int, bm: int, bn: int,
                  bk: int) -> tuple[int, int, int]:
-    """Shrink blocks to the (padded) problem so tiny shapes don't over-pad."""
+    """Shrink blocks to the (padded) problem so tiny shapes don't over-pad.
+
+    ``bn``/``bk`` are halved (down to 128) until they divide N/K padded to
+    128: a block that does not divide the weight's dims makes every call
+    copy the whole weight into a padded buffer (a 1x4 shard of d_ff=18944
+    is 4736 wide, which a 512 block would pad to 5120)."""
+    def fit(b: int, d: int) -> int:
+        d = max(128, 128 * (-(-d // 128)))
+        b = min(b, d)
+        while b > 128 and d % b:
+            b //= 2
+        return b
     bm = min(bm, max(8, 8 * (-(-M // 8))))
-    bn = min(bn, max(128, 128 * (-(-N // 128))))
-    bk = min(bk, max(128, 128 * (-(-K // 128))))
-    return bm, bn, bk
+    return bm, fit(bn, N), fit(bk, K)
 
 
 def pick_blocks(op: str, M: int, K: int, N: int, backend: str,
@@ -145,8 +173,10 @@ def pick_blocks(op: str, M: int, K: int, N: int, backend: str,
                 run()
                 reps.append(time.perf_counter() - t0)
             dt = sorted(reps)[len(reps) // 2]       # median
-        except Exception:                           # infeasible candidate
-            continue
+        except Exception:
+            if backend == "pallas":                 # a kernel that fails to
+                raise                               # compile must surface
+            continue                                # infeasible in interpret
         if dt < best_t:
             best, best_t = blocks, dt
     _BLOCK_CACHE[key] = best
@@ -496,6 +526,8 @@ def pick_variant(op: str, M: int, K: int, N: int, backend: str,
                 reps.append(time.perf_counter() - t0)
             dt = sorted(reps)[len(reps) // 2]
         except Exception:
+            if backend == "pallas":
+                raise
             continue
         if dt < best_t:
             best, best_t = name, dt
@@ -528,6 +560,30 @@ def quantize_activations(x2: jax.Array, bits: int):
     return _quantize_with_scale(x2, a_scale, qmax), a_scale
 
 
+@functools.partial(jax.jit, static_argnums=1)
+def absmax_codes(wf: jax.Array, qmax: int):
+    """Per-output-channel symmetric quant math shared by every weight
+    quantizer: ([..., K, N] float codes in [-qmax-1, qmax], [..., 1, N]
+    f32 scale).  Always compiled, so an eager call and one traced inside a
+    larger jit give the same bits (XLA folds the division by the constant
+    ``qmax`` into a reciprocal multiply only when it compiles)."""
+    wf = wf.astype(jnp.float32)
+    scale = jnp.maximum(
+        jnp.max(jnp.abs(wf), axis=-2, keepdims=True) / qmax, 1e-8)
+    return jnp.clip(jnp.round(wf / scale), -qmax - 1, qmax), scale
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _meanabs_codes(wf: jax.Array, wbits) -> tuple[jax.Array, jax.Array]:
+    """BitNet-b1.58 per-channel mean-|w| quant (ternary / w1), compiled for
+    the same reason as :func:`absmax_codes`."""
+    wf = wf.astype(jnp.float32)
+    scale = jnp.maximum(jnp.mean(jnp.abs(wf), axis=-2, keepdims=True), 1e-8)
+    if wbits == "ternary":
+        return jnp.clip(jnp.round(wf / scale), -1, 1), scale
+    return jnp.where(wf >= 0, 1, -1), scale
+
+
 def quantize_weights(wf: jax.Array, bits: int, pack: bool = False):
     """Per-output-channel symmetric quant: [K, N] f32 -> (codes, [1, N] scale).
 
@@ -546,10 +602,8 @@ def quantize_weights(wf: jax.Array, bits: int, pack: bool = False):
                          f"got bits={bits}")
     global WEIGHT_QUANT_COUNT
     WEIGHT_QUANT_COUNT += 1
-    qmax = 2 ** (bits - 1) - 1
-    w_scale = jnp.max(jnp.abs(wf), axis=0, keepdims=True) / qmax   # [1, N]
-    w_scale = jnp.maximum(w_scale, 1e-8)
-    w_q = jnp.clip(jnp.round(wf / w_scale), -qmax - 1, qmax).astype(jnp.int8)
+    codes, w_scale = absmax_codes(wf, 2 ** (bits - 1) - 1)        # [1, N]
+    w_q = codes.astype(jnp.int8)
     if pack:
         if wf.shape[0] % 2:
             raise ValueError(
@@ -578,20 +632,10 @@ def quantize_weights_planes(wf: jax.Array, wbits):
             "pad the contraction dim before quantizing")
     global WEIGHT_QUANT_COUNT
     WEIGHT_QUANT_COUNT += 1
-    wf = wf.astype(jnp.float32)
     if wbits in ("ternary", 1):
-        w_scale = jnp.maximum(jnp.mean(jnp.abs(wf), axis=-2, keepdims=True),
-                              1e-8)                             # [..., 1, N]
-        if wbits == "ternary":
-            codes = jnp.clip(jnp.round(wf / w_scale), -1, 1)
-        else:
-            codes = jnp.where(wf >= 0, 1, -1)
+        codes, w_scale = _meanabs_codes(wf, wbits)             # [..., 1, N]
     else:
-        b = int(wbits)
-        qmax = 2 ** (b - 1) - 1
-        w_scale = jnp.maximum(
-            jnp.max(jnp.abs(wf), axis=-2, keepdims=True) / qmax, 1e-8)
-        codes = jnp.clip(jnp.round(wf / w_scale), -qmax - 1, qmax)
+        codes, w_scale = absmax_codes(wf, 2 ** (int(wbits) - 1) - 1)
     planes = planes_from_codes(codes.astype(jnp.int32), wbits)
     return pack_bitplanes(planes), w_scale
 
@@ -656,6 +700,8 @@ def pick_formulation(wbits, abits: int, K: int, N: int,
                 reps.append(time.perf_counter() - t0)
             timings[name] = sorted(reps)[len(reps) // 2]
         except Exception:
+            if be == "pallas":
+                raise
             continue
     best = min(timings, key=timings.get) if timings else default
     _FORMULATION_CACHE[key] = best

@@ -30,7 +30,7 @@ def _threshold_body(acc_ref, thr_ref, sign_ref, out_ref):
 
 def threshold_pallas(acc: jax.Array, thresholds: jax.Array, sign: jax.Array,
                      *, bm: int = DEFAULT_BM, bn: int = DEFAULT_BN,
-                     interpret: bool = True) -> jax.Array:
+                     interpret: bool) -> jax.Array:
     """acc: [M, N] int32; thresholds: [N, K] f32; sign: [N] f32 -> int32 codes.
 
     M, N must be pre-padded to block multiples (ops.py handles it).

@@ -1,12 +1,18 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+# a compile-only sweep on 512 host devices: it and the children it spawns
+# stay off any accelerator, and it keeps whatever XLA flags the caller set
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["XLA_FLAGS"] = " ".join(filter(None, (
+    os.environ.get("XLA_FLAGS"),
+    "--xla_force_host_platform_device_count=512")))
 
 """Multi-pod dry-run: lower + compile every (arch x shape) on the production
 meshes, dump memory/cost/collective analysis to JSON.
 
-Must be run as a script/subprocess (it forces 512 host devices before any jax
-import).  ``--all`` orchestrates one subprocess per cell so a pathological
-compile can't take the whole sweep down, and cells run in parallel.
+Must be run as a script/subprocess (it pins JAX to the CPU and forces 512
+host devices before any jax import).  ``--all`` orchestrates one
+subprocess per cell so a pathological compile can't take the whole sweep
+down, and cells run in parallel.
 
 Usage:
   python -m repro.launch.dryrun --arch qwen2-7b --shape train_4k [--multi-pod]
@@ -181,7 +187,9 @@ def orchestrate(args) -> int:
             if args.multi_pod:
                 cmd.append("--multi-pod")
             log = open(os.path.join(out_dir, tag + ".log"), "w")
-            jobs.append((subprocess.Popen(cmd, stdout=log, stderr=log), tag, out))
+            jobs.append((subprocess.Popen(
+                cmd, stdout=log, stderr=log,
+                env={**os.environ, "JAX_PLATFORMS": "cpu"}), tag, out))
             print(f"[launch] {tag}")
         still = []
         for proc, tag, out in jobs:

@@ -142,20 +142,25 @@ def _init_block(key, cfg: ModelConfig, spec: BlockSpec) -> dict:
     return p
 
 
-def init_params(key, cfg: ModelConfig) -> dict:
-    keys = jax.random.split(key, cfg.n_layers + 4)
+def group_keys(keys: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """Per-block init keys of ``init_params``' split, shaped [G, P, ...]:
+    block (group g, pattern position pi) uses ``keys[g * P + pi]``."""
     G, P = cfg.n_groups, len(cfg.pattern)
-    # stack per pattern-position
-    blocks = []
-    for pi, spec in enumerate(cfg.pattern):
-        per_group = [
-            _init_block(keys[g * P + pi], cfg, spec) for g in range(G)
-        ]
-        blocks.append(jax.tree_util.tree_map(
-            lambda *xs: jnp.stack(xs), *per_group))
+    return keys[:G * P].reshape((G, P) + keys.shape[1:])
+
+
+def init_group(gkeys: jax.Array, cfg: ModelConfig) -> tuple:
+    """One group's block params, one dict per pattern position."""
+    return tuple(_init_block(gkeys[pi], cfg, spec)
+                 for pi, spec in enumerate(cfg.pattern))
+
+
+def init_outer(keys: jax.Array, cfg: ModelConfig) -> dict:
+    """Everything outside the scanned blocks: embedding, final norm, LM head
+    and the shared attention block, from the tail of ``init_params``'
+    split."""
     params = {
         "embed": init_embedding(keys[-1], cfg.vocab, cfg.d_model, cfg.pdtype),
-        "blocks": tuple(blocks),
         "final_norm": init_norm(cfg.d_model, cfg.pdtype),
     }
     if not cfg.tie_embeddings:
@@ -172,6 +177,15 @@ def init_params(key, cfg: ModelConfig) -> dict:
                             cfg.pdtype),
         }
     return params
+
+
+def init_params(key, cfg: ModelConfig) -> dict:
+    keys = jax.random.split(key, cfg.n_layers + 4)
+    gk = group_keys(keys, cfg)
+    # stack per pattern-position
+    per_group = [init_group(gk[g], cfg) for g in range(cfg.n_groups)]
+    blocks = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *per_group)
+    return {**init_outer(keys, cfg), "blocks": blocks}
 
 
 # ---------------------------------------------------------------------------

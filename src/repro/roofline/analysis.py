@@ -1,4 +1,4 @@
-"""Three-term roofline from compiled artifacts (TPU v5e constants).
+"""Three-term roofline from compiled artifacts (per-device-kind peaks).
 
   compute    = HLO_FLOPs_per_device / peak_FLOPs
   memory     = HLO_bytes_per_device / HBM_bw
@@ -17,10 +17,27 @@ import dataclasses
 import re
 from typing import Optional
 
-# TPU v5e, from the assignment
-PEAK_FLOPS = 197e12          # bf16 FLOP/s per chip
-HBM_BW = 819e9               # bytes/s per chip
-LINK_BW = 50e9               # bytes/s per ICI link
+# Per-chip peaks, keyed by ``jax.Device.device_kind``.  Source: Google
+# Cloud TPU documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB HBM at
+# 819 GB/s, 1,600 Gbit/s of inter-chip interconnect (four links of
+# 50 GB/s).
+PEAKS = {
+    "TPU v5 lite": {"bf16_flops": 197e12, "hbm_bw": 819e9, "link_bw": 50e9},
+}
+# the production meshes the compile-only dry-run models are v5e pods
+DRYRUN_DEVICE_KIND = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> dict:
+    """The peak table row for ``device_kind``; a device that is not in the
+    table is an error, never a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no roofline peaks for device kind {device_kind!r}; known: "
+            f"{sorted(PEAKS)} — add the chip's published peaks to "
+            "roofline.analysis.PEAKS") from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2, "bf16": 2,
@@ -107,10 +124,11 @@ def parse_collectives(hlo_text: str) -> list[Collective]:
     return out
 
 
-def roofline_terms(cost: dict, hlo_text: str) -> dict:
-    """Returns the three terms (seconds) + supporting detail."""
-    if isinstance(cost, (list, tuple)):   # jax<0.5 returns [dict] per program
-        cost = cost[0] if cost else {}
+def roofline_terms(cost: dict, hlo_text: str,
+                   device_kind: str = DRYRUN_DEVICE_KIND) -> dict:
+    """Returns the three terms (seconds) on ``device_kind``'s peaks +
+    supporting detail."""
+    peak = peaks(device_kind)
     flops = float(cost.get("flops", 0.0))
     hbm_bytes = float(cost.get("bytes accessed", 0.0))
     colls = parse_collectives(hlo_text)
@@ -122,9 +140,9 @@ def roofline_terms(cost: dict, hlo_text: str) -> dict:
         d["link_bytes"] += c.link_bytes
     top = sorted(colls, key=lambda c: -c.link_bytes)[:8]
     return {
-        "compute_s": flops / PEAK_FLOPS,
-        "memory_s": hbm_bytes / HBM_BW,
-        "collective_s": coll_bytes / LINK_BW,
+        "compute_s": flops / peak["bf16_flops"],
+        "memory_s": hbm_bytes / peak["hbm_bw"],
+        "collective_s": coll_bytes / peak["link_bw"],
         "hlo_flops_per_device": flops,
         "hlo_bytes_per_device": hbm_bytes,
         "collective_link_bytes": coll_bytes,
@@ -219,7 +237,7 @@ def plan_mixed_bits(params, target_bits: float, abits: int = 4,
     """Choose per-leaf tmac weight widths hitting a target average bit width.
 
     The roofline says decode GEMVs are memory-bound (at M = batch tokens,
-    ``memory_s = weight_bytes / HBM_BW`` dwarfs ``compute_s`` until M is in
+    ``memory_s = weight_bytes / hbm_bw`` dwarfs ``compute_s`` until M is in
     the hundreds), so decode latency IS weight bytes and the tmac kernel's
     cost is linear in the plane count either way — minimizing total weight
     bits minimizes both terms at once.  Greedy: repeatedly demote the leaf

@@ -42,6 +42,13 @@ from repro.models import encdec, transformer
 from repro.serve.faults import CacheCorruption
 
 NEG_INF = -1e30
+# Compile options of every served program.  With XLA's excess precision on,
+# a bf16 value may stay in f32 wherever fusion allows, while the Pallas
+# kernels' fused epilogue always rounds; where a value rounds then depends on
+# the kernel backend and the mesh, a 1-ulp difference flips int4 activation
+# codes, and the same params give different tokens.  Off, every program
+# rounds where the jaxpr says.
+STEP_COMPILER_OPTIONS = {"xla_allow_excess_precision": False}
 
 
 @dataclasses.dataclass
@@ -335,10 +342,12 @@ class Engine:
                     f"as the null page — give every shard at least 2 pages")
         mod = encdec if self.is_encdec else transformer
         self._mod = mod
-        self._prefill = jax.jit(lambda p, *a: mod.prefill(p, cfg, *a))
+        self._prefill = jax.jit(lambda p, *a: mod.prefill(p, cfg, *a),
+                                compiler_options=STEP_COMPILER_OPTIONS)
         # donate the cache: decode updates it in place (halves residency)
         self._decode = jax.jit(lambda p, t, c, pos: mod.decode_step(
-            p, cfg, t, c, pos), donate_argnums=2)
+            p, cfg, t, c, pos), donate_argnums=2,
+            compiler_options=STEP_COMPILER_OPTIONS)
         self._admit_fn = self._build_admit_fn()
         self._step_fns: dict[tuple, callable] = {}
         # fault injection (serve.faults): a FaultPlan applied at the two
@@ -386,12 +395,14 @@ class Engine:
     #    shard_map-wrapped variants; the impls themselves are shared) --------
 
     def _build_admit_fn(self):
-        return jax.jit(self._admit_impl, donate_argnums=1)
+        return jax.jit(self._admit_impl, donate_argnums=1,
+                       compiler_options=STEP_COMPILER_OPTIONS)
 
     def _build_step_fn(self, C: int, chunk: int, greedy: bool,
                        spec: bool = False):
         return jax.jit(self._make_step_impl(C, chunk, greedy, spec),
-                       donate_argnums=1)
+                       donate_argnums=1,
+                       compiler_options=STEP_COMPILER_OPTIONS)
 
     # -- scheduler-facing API ------------------------------------------------
 

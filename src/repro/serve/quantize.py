@@ -49,14 +49,11 @@ def quantize_leaf(w: jax.Array, bits: int):
     """
     from repro.kernels.lutmul import ops as lut_ops
     lut_ops.WEIGHT_QUANT_COUNT += 1
-    qmax = 2 ** (bits - 1) - 1
-    scale = jnp.max(jnp.abs(w.astype(jnp.float32)), axis=-2, keepdims=True) \
-        / qmax
-    scale = jnp.maximum(scale, 1e-8)
-    q = jnp.clip(jnp.round(w / scale), -qmax - 1, qmax).astype(jnp.int8)
+    codes, scale = lut_ops.absmax_codes(w, 2 ** (bits - 1) - 1)
+    q = codes.astype(jnp.int8)
     if bits == 4:
         q = jnp.swapaxes(pack_int4(jnp.swapaxes(q, -1, -2)), -1, -2)
-    return {"w_q": q, "w_scale": scale.astype(jnp.float32)}
+    return {"w_q": q, "w_scale": scale}
 
 
 _quantize_leaf = quantize_leaf          # backwards-compat alias
@@ -159,6 +156,41 @@ def quantize_params_for_serving(params, mode: str = "w4a4_mxu",
         return tree
 
     return walk(params)
+
+
+def init_quantized_params(key, cfg, mode: str):
+    """Random serving params of a decoder LM, quantized as they are built.
+
+    Bit-identical to ``quantize_params_for_serving(init_params(key, cfg),
+    mode)`` and with the same ``WEIGHT_QUANT_COUNT``, but the
+    float model never exists: the embedding and head come first, then each
+    layer group is initialised from ``init_params``' own keys, quantized
+    by one jitted walk (traced once, so each leaf counts once) and written
+    into the stacked code buffers in place.  Peak memory is the quantized
+    model plus one group's float weights — how a full-width model reaches
+    a device that cannot hold it in float.
+    """
+    from repro.models import transformer
+
+    keys = jax.random.split(key, cfg.n_layers + 4)
+    # donated: the float head is freed once its codes exist, and the
+    # embedding passes through without a copy
+    outer = jax.jit(lambda p: quantize_params_for_serving(p, mode),
+                    donate_argnums=0)(transformer.init_outer(keys, cfg))
+    gkeys = transformer.group_keys(keys, cfg)
+    quant = jax.jit(lambda blocks: quantize_params_for_serving(
+        {"blocks": blocks}, mode)["blocks"])
+    put = jax.jit(lambda buf, piece, g: jax.tree_util.tree_map(
+        lambda b, p: b.at[g].set(p), buf, piece), donate_argnums=0)
+    blocks = None
+    for g in range(cfg.n_groups):
+        piece = quant(transformer.init_group(gkeys[g], cfg))
+        if blocks is None:
+            blocks = jax.tree_util.tree_map(
+                lambda p: jnp.zeros((cfg.n_groups,) + p.shape, p.dtype),
+                piece)
+        blocks = put(blocks, piece, g)
+    return {**outer, "blocks": blocks}
 
 
 def _draftable(leaf, draft_planes: int) -> bool:

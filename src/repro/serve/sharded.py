@@ -25,7 +25,6 @@ from __future__ import annotations
 import dataclasses
 
 import jax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.dist import tp as tp_lib
@@ -110,11 +109,12 @@ class ShardedEngine(Engine):
         # have normalized, e.g. P("data") -> P() on a size-1 axis) reshard
         # instead of retracing — the no-retrace-after-warmup invariant
         return jax.jit(
-            shard_map(body, mesh=self.mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=False),
+            jax.shard_map(body, mesh=self.mesh, in_specs=in_specs,
+                          out_specs=out_specs, check_vma=False),
             in_shardings=jax.tree_util.tree_map(
                 lambda s: NamedSharding(self.mesh, s), in_specs),
-            donate_argnums=1)
+            donate_argnums=1,
+            compiler_options=engine_lib.STEP_COMPILER_OPTIONS)
 
     def _build_admit_fn(self):
         d = self._dspec

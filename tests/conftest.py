@@ -5,12 +5,5 @@ import sys
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 
-try:
-    import hypothesis  # noqa: F401
-except ImportError:          # container without hypothesis: use the shim
-    import _hypothesis_stub
-    _hypothesis_stub.install(sys.modules)
-
-
 def pytest_configure(config):
     config.addinivalue_line("markers", "slow: long-running integration tests")

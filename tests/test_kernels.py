@@ -204,3 +204,39 @@ def test_prequant_malformed_packed_rejected_on_all_backends():
         with pytest.raises(ValueError, match="K//2"):
             ops.prequant_matmul(x, bad_wq, w_scale, mode="w4a4_lut",
                                 backend=backend)
+
+
+def test_backend_env_cannot_swap_kernels_on_tpu(monkeypatch):
+    """On a TPU, REPRO_KERNEL_BACKEND may select only the Pallas kernels
+    outside a test; an unknown name is an error anywhere."""
+    monkeypatch.setattr(ops, "_BACKEND", None)
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: "tpu")
+    monkeypatch.delenv("PYTEST_CURRENT_TEST", raising=False)
+    for name in ("interpret", "ref"):
+        monkeypatch.setenv("REPRO_KERNEL_BACKEND", name)
+        with pytest.raises(RuntimeError, match="on a TPU"):
+            ops.get_backend()
+    monkeypatch.setenv("REPRO_KERNEL_BACKEND", "pallas")
+    assert ops.get_backend() == "pallas"
+    monkeypatch.delenv("REPRO_KERNEL_BACKEND")
+    assert ops.get_backend() == "pallas"
+    monkeypatch.setenv("REPRO_KERNEL_BACKEND", "Pallas")
+    with pytest.raises(ValueError, match="expected one of"):
+        ops.get_backend()
+
+
+@pytest.mark.parametrize("M,K,N,want", [
+    (8, 3584, 18944, (8, 512, 512)),      # qwen2-7b MLP up, one chip
+    (8, 18944, 3584, (8, 512, 512)),      # MLP down
+    (8, 3584, 4736, (8, 128, 512)),       # MLP up, one of 4 shards
+    (8, 4736, 3584, (8, 512, 128)),       # MLP down, row-parallel shard
+    (8, 896, 3584, (8, 512, 128)),        # wo, head-sharded over 4
+    (128, 3584, 152064, (128, 512, 512)),  # head, prefill M
+    (5, 100, 200, (8, 256, 128)),         # tiny: clipped to the padding
+])
+def test_default_blocks_divide_padded_dims(M, K, N, want):
+    """The default blocks never make a call pad a weight whose dims are
+    multiples of 128 (padding would copy the weight on every call)."""
+    bm, bn, bk = ops._clip_blocks(M, K, N, *ops._CANDIDATES[0])
+    assert (bm, bn, bk) == want
+    assert (-(-N // 128) * 128) % bn == 0 and (-(-K // 128) * 128) % bk == 0

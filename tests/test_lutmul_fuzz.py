@@ -4,9 +4,8 @@ Every drawn (M, K, N, weight bits, block shape, contract dtype) combination
 must make the Pallas kernels (interpret mode — the CPU lowering of the TPU
 kernel) agree EXACTLY with the pure-jnp oracles in ``kernels/lutmul/ref.py``:
 integer accumulators bit for bit, fused-dequant outputs bit for bit against
-the oracle's epilogue order.  Runs under real hypothesis when installed, or
-the deterministic shim in ``tests/_hypothesis_stub.py`` (fixed seed) —
-``REPRO_FUZZ_EXAMPLES`` bounds the example count so CI stays fast.
+the oracle's epilogue order.  ``REPRO_FUZZ_EXAMPLES`` bounds the
+Hypothesis example count so CI stays fast.
 """
 import os
 

@@ -68,3 +68,27 @@ def test_split_head_params_forward():
     np.testing.assert_allclose(np.asarray(pl),
                                np.asarray(full[:, 8], np.float32),
                                rtol=5e-4, atol=5e-4)
+
+
+@pytest.mark.parametrize("mode", ["w4a4_lut", "w4a4_tmac", "ternary_a4_tmac",
+                                  "w8a8"])
+def test_streamed_quantized_load_matches_quantize_after_init(mode):
+    """The full-width loader (quantize each layer group as it is built)
+    returns exactly ``quantize_params_for_serving(init_params(...))``, with
+    one weight-quantization event per leaf either way."""
+    from repro.kernels.lutmul import ops
+    from repro.serve.quantize import init_quantized_params
+    cfg = configs.get_config("qwen2-7b", smoke=True, quant=mode)
+    key = jax.random.PRNGKey(3)
+    c0 = ops.WEIGHT_QUANT_COUNT
+    want = quantize_params_for_serving(T.init_params(key, cfg), mode=mode)
+    c1 = ops.WEIGHT_QUANT_COUNT
+    got = init_quantized_params(key, cfg, mode)
+    assert ops.WEIGHT_QUANT_COUNT - c1 == c1 - c0 > 0
+    assert (jax.tree_util.tree_structure(got)
+            == jax.tree_util.tree_structure(want))
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(want),
+                            jax.tree_util.tree_leaves(got)):
+        assert a.dtype == b.dtype and a.shape == b.shape, path
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                      err_msg=jax.tree_util.keystr(path))

@@ -41,6 +41,13 @@ def test_roofline_terms_and_dominance():
     assert analysis.dominant(terms) == "memory"
 
 
+def test_unknown_device_kind_has_no_peaks():
+    """Peaks come from the table for the device kind, never a default."""
+    assert analysis.peaks("TPU v5 lite")["hbm_bw"] == 819e9
+    with pytest.raises(ValueError, match="no roofline peaks"):
+        analysis.roofline_terms({"flops": 1.0}, HLO, device_kind="cpu")
+
+
 def test_model_flops():
     assert analysis.model_flops("train", 1e9, 8, 1024) == 6e9 * 8 * 1024
     assert analysis.model_flops("decode", 1e9, 128, 4096) == 2e9 * 128

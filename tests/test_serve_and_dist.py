@@ -138,7 +138,6 @@ def test_compressed_psum_multidevice_subprocess():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
         from repro.optim import grad_compress
         from repro.dist.sharding import make_mesh
         mesh = make_mesh((8,), ("dp",))
@@ -148,8 +147,9 @@ def test_compressed_psum_multidevice_subprocess():
             out, r2 = grad_compress.compressed_psum(
                 {"w": g[0]}, {"w": r[0]}, "dp")
             return out["w"][None], r2["w"][None]
-        out, r2 = shard_map(f, mesh=mesh, in_specs=(P("dp"), P("dp")),
-                            out_specs=(P("dp"), P("dp")))(g, r)
+        out, r2 = jax.shard_map(f, mesh=mesh, in_specs=(P("dp"), P("dp")),
+                                out_specs=(P("dp"), P("dp")),
+                                check_vma=False)(g, r)
         exact = jnp.mean(g, axis=0)
         got = np.asarray(out[0])
         err = np.abs(got - np.asarray(exact)).max()
@@ -163,3 +163,24 @@ def test_compressed_psum_multidevice_subprocess():
                          capture_output=True, text=True, timeout=300)
     assert out.returncode == 0, out.stderr[-3000:]
     assert "OK" in out.stdout
+
+
+def test_compile_cache_directory(monkeypatch):
+    """The chip entry points keep XLA's cache in $JAX_COMPILATION_CACHE_DIR
+    (set nothing else in code) or else in the fixed, git-ignored
+    <repo>/.jax_cache."""
+    from repro.launch import compile_cache
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+        assert compile_cache.enable_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        got = compile_cache.enable_compile_cache()
+        assert got == os.path.join(root, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == got
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    with open(os.path.join(root, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()

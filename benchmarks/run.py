@@ -191,10 +191,10 @@ def main(argv=None) -> None:
     enable_compile_cache()
 
     from benchmarks import (fpga_roofline, kernel_bench, lut_cost, lut_init,
-                            qat_accuracy, resource_breakdown, serving_bench,
+                            qat_accuracy, resource_breakdown,
                             throughput_table2)
     mods = [lut_init, lut_cost, fpga_roofline, throughput_table2,
-            resource_breakdown, kernel_bench, qat_accuracy, serving_bench]
+            resource_breakdown, kernel_bench, qat_accuracy]
     if args.only:
         mods = [m for m in mods if m.__name__.split(".")[-1] in args.only]
     records = []

@@ -216,6 +216,7 @@ def lutmul_pallas(a_codes: jax.Array, w_packed: jax.Array, table: jax.Array,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.int32),
         interpret=interpret,
+        name=f"lutmul_{impl}",
     )(a_codes, w_packed, table)
 
 
@@ -359,6 +360,7 @@ def lutmul_tmac_pallas(a_q: jax.Array, w_planes: jax.Array, *,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.int32),
         interpret=interpret,
+        name="lutmul_tmac",
     )(a_q, w_planes, *g_ops)
 
 
@@ -393,6 +395,7 @@ def int_matmul_pallas(a: jax.Array, w: jax.Array, *, bm: int = DEFAULT_BM,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.int32),
         interpret=interpret,
+        name="int_matmul",
     )(a, w)
 
 
@@ -453,6 +456,7 @@ def lutmul_fused_pallas(a_codes: jax.Array, w_packed: jax.Array,
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
         interpret=interpret,
+        name="lutmul_onehot_fused",
     )(a_codes, w_packed, table, a_scale, w_scale)
 
 
@@ -508,6 +512,7 @@ def lutmul_tmac_fused_pallas(a_q: jax.Array, w_planes: jax.Array,
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
         interpret=interpret,
+        name="lutmul_tmac_fused",
     )(a_q, w_planes, *g_ops, a_scale, w_scale)
 
 
@@ -554,4 +559,5 @@ def int_matmul_fused_pallas(a: jax.Array, w: jax.Array, a_scale: jax.Array,
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
         interpret=interpret,
+        name="int_matmul_fused",
     )(a, w, a_scale, w_scale)

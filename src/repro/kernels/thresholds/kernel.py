@@ -49,4 +49,5 @@ def threshold_pallas(acc: jax.Array, thresholds: jax.Array, sign: jax.Array,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.int32),
         interpret=interpret,
+        name="thresholds",
     )(acc, thresholds, sign)

@@ -278,6 +278,15 @@ def paged_write(pool: jax.Array, table: jax.Array, slot: jax.Array,
     return pool.at[page, slot % ps].set(new[:, 0].astype(pool.dtype))
 
 
+def _read_kv(cache_k: jax.Array, cache_v: jax.Array,
+             table: Optional[jax.Array]) -> tuple:
+    """The dense [B, T, ...] K and V buffers attention reads: the caches
+    themselves, or their ordered page gather under a page table."""
+    if table is None:
+        return cache_k, cache_v
+    return paged_gather(cache_k, table), paged_gather(cache_v, table)
+
+
 def _write_kv_block(cache: jax.Array, new: jax.Array,
                     start: jax.Array) -> jax.Array:
     """Contiguous S-token cache write: cache [B,T,...], new [B,S,...],
@@ -343,17 +352,18 @@ def decode_attention(p: Params, x: jax.Array, cache_k: jax.Array,
         q = apply_rope(q, posb, rope_theta)
         k = apply_rope(k, posb, rope_theta)
     slot = posv % T if rolling else jnp.clip(posv, 0, T - 1)
-    if paged:
-        cache_k = paged_write(cache_k, table, slot, k)
-        cache_v = paged_write(cache_v, table, slot, v)
-        dense_k = paged_gather(cache_k, table)
-        dense_v = paged_gather(cache_v, table)
-    else:
-        cache_k = dense_k = _write_kv_slot(cache_k, k, slot)
-        cache_v = dense_v = _write_kv_slot(cache_v, v, slot)
-    k_pos = decode_kv_positions(posv, T, rolling)
-    out = full_attention(q, dense_k, dense_v, posb, k_pos, causal=True,
-                         window=window, logit_softcap=logit_softcap)
+    with jax.named_scope("kv_write"):
+        if paged:
+            cache_k = paged_write(cache_k, table, slot, k)
+            cache_v = paged_write(cache_v, table, slot, v)
+        else:
+            cache_k = _write_kv_slot(cache_k, k, slot)
+            cache_v = _write_kv_slot(cache_v, v, slot)
+    with jax.named_scope("attend"):
+        dense_k, dense_v = _read_kv(cache_k, cache_v, table)
+        k_pos = decode_kv_positions(posv, T, rolling)
+        out = full_attention(q, dense_k, dense_v, posb, k_pos, causal=True,
+                             window=window, logit_softcap=logit_softcap)
     y = _proj_out(p, out.astype(compute_dtype), B, 1, n_heads, head_dim,
                   quant, compute_dtype)
     return y, cache_k, cache_v
@@ -396,23 +406,25 @@ def decode_attention_multi(p: Params, x: jax.Array, cache_k: jax.Array,
     elif rope_mode == "rope":
         q = apply_rope(q, q_pos, rope_theta)
         k = apply_rope(k, q_pos, rope_theta)
-    if paged:
-        # S sequential table writes (deterministic, and unmapped/free rows
-        # collapse into the null page exactly like single-token decode)
-        for i in range(S):
-            slot = jnp.clip(posv + i, 0, T - 1)
-            cache_k = paged_write(cache_k, table, slot, k[:, i:i + 1])
-            cache_v = paged_write(cache_v, table, slot, v[:, i:i + 1])
-        dense_k = paged_gather(cache_k, table)
-        dense_v = paged_gather(cache_v, table)
-    else:
-        cache_k = dense_k = _write_kv_block(cache_k, k, posv)
-        cache_v = dense_v = _write_kv_block(cache_v, v, posv)
-    # free rows keep posv < 0 so every key stays masked for them
-    k_pos = decode_kv_positions(jnp.where(posv >= 0, posv + (S - 1), posv),
-                                T, rolling=False)
-    out = full_attention(q, dense_k, dense_v, q_pos, k_pos, causal=True,
-                         window=None, logit_softcap=logit_softcap)
+    with jax.named_scope("kv_write"):
+        if paged:
+            # S sequential table writes (deterministic, and unmapped/free
+            # rows collapse into the null page exactly like single-token
+            # decode)
+            for i in range(S):
+                slot = jnp.clip(posv + i, 0, T - 1)
+                cache_k = paged_write(cache_k, table, slot, k[:, i:i + 1])
+                cache_v = paged_write(cache_v, table, slot, v[:, i:i + 1])
+        else:
+            cache_k = _write_kv_block(cache_k, k, posv)
+            cache_v = _write_kv_block(cache_v, v, posv)
+    with jax.named_scope("attend"):
+        dense_k, dense_v = _read_kv(cache_k, cache_v, table)
+        # free rows keep posv < 0 so every key stays masked for them
+        k_pos = decode_kv_positions(
+            jnp.where(posv >= 0, posv + (S - 1), posv), T, rolling=False)
+        out = full_attention(q, dense_k, dense_v, q_pos, k_pos, causal=True,
+                             window=None, logit_softcap=logit_softcap)
     y = _proj_out(p, out.astype(compute_dtype), B, S, n_heads, head_dim,
                   quant, compute_dtype)
     return y, cache_k, cache_v
@@ -508,16 +520,18 @@ def decode_attention_int8(p: Params, x: jax.Array, cache: dict,
     cache = dict(cache)
     write = ((lambda c, n: paged_write(c, table, slot, n)) if paged
              else (lambda c, n: _write_kv_slot(c, n, slot)))
-    cache["k"] = write(cache["k"], k_new)
-    cache["v"] = write(cache["v"], v_new)
-    cache["k_scale"] = write(cache["k_scale"], ks_new)
-    cache["v_scale"] = write(cache["v_scale"], vs_new)
+    with jax.named_scope("kv_write"):
+        cache["k"] = write(cache["k"], k_new)
+        cache["v"] = write(cache["v"], v_new)
+        cache["k_scale"] = write(cache["k_scale"], ks_new)
+        cache["v_scale"] = write(cache["v_scale"], vs_new)
     dense = ((lambda c: paged_gather(c, table)) if paged else (lambda c: c))
-    k_pos = decode_kv_positions(posv, T, rolling=False)
-    out = int8_kv_attention(q, dense(cache["k"]), dense(cache["k_scale"]),
-                            dense(cache["v"]), dense(cache["v_scale"]),
-                            posb, k_pos, window=window,
-                            logit_softcap=logit_softcap)
+    with jax.named_scope("attend"):
+        k_pos = decode_kv_positions(posv, T, rolling=False)
+        out = int8_kv_attention(q, dense(cache["k"]), dense(cache["k_scale"]),
+                                dense(cache["v"]), dense(cache["v_scale"]),
+                                posb, k_pos, window=window,
+                                logit_softcap=logit_softcap)
     y = _proj_out(p, out.astype(compute_dtype), B, 1, n_heads, head_dim,
                   quant, compute_dtype)
     return y, cache

@@ -799,10 +799,11 @@ class Engine:
             tok0, done0 = tok, done
 
             def sample(logits, key_i):
-                if greedy:
-                    return sample_logits(logits, key_i, 0.0, 0, 1.0)
-                return sample_logits(logits, key_i, temperature, top_k,
-                                     top_p)
+                with jax.named_scope("sample"):
+                    if greedy:
+                        return sample_logits(logits, key_i, 0.0, 0, 1.0)
+                    return sample_logits(logits, key_i, temperature, top_k,
+                                         top_p)
 
             if C:
                 # chunk-lane rows are GLOBAL slot ids: under a data mesh
@@ -837,8 +838,10 @@ class Engine:
 
                 xs = (c_slot, c_tok, c_pos, c_first, c_b1,
                       jnp.arange(C, dtype=jnp.int32))
-                (cache, tok, pos, done, ok, tok0, done0), _ = jax.lax.scan(
-                    fill, (cache, tok, pos, done, ok, tok0, done0), xs)
+                with jax.named_scope("chunk_lane"):
+                    (cache, tok, pos, done, ok, tok0, done0), _ = \
+                        jax.lax.scan(fill, (cache, tok, pos, done, ok, tok0,
+                                            done0), xs)
 
             if spec:
                 # -- speculative decode lane: draft K / verify 1 -----------
@@ -866,16 +869,19 @@ class Engine:
                     dpos = jnp.where(done, dpos, dpos + 1)
                     return (cache, nxt, dpos), nxt
 
-                (cache, _, _), drafts = jax.lax.scan(
-                    draft, (cache, tok, pos), jnp.arange(K, dtype=jnp.int32))
+                with jax.named_scope("draft"):
+                    (cache, _, _), drafts = jax.lax.scan(
+                        draft, (cache, tok, pos),
+                        jnp.arange(K, dtype=jnp.int32))
                 drafts = drafts.T                               # [B, K]
                 # ONE batched target forward over [t0, d_1..d_K]: logits[i]
                 # conditions on the accepted-so-far prefix exactly like i
                 # sequential target steps would (verify_step writes target
                 # bits over every speculative slot before attending)
                 vtoks = jnp.concatenate([tok[:, None], drafts], axis=1)
-                logits, cache = mod.verify_step(params, cfg, vtoks, cache,
-                                                pos, tables=tables)
+                with jax.named_scope("verify"):
+                    logits, cache = mod.verify_step(params, cfg, vtoks,
+                                                    cache, pos, tables=tables)
                 ok = ok & (jnp.isfinite(logits).all(axis=(-2, -1)) | done)
                 v = jnp.stack(
                     [sample(logits[:, i],
@@ -914,9 +920,10 @@ class Engine:
                     done = done | ((nxt == eos) & (eos >= 0))
                     return (cache, nxt, pos, done, ok), (nxt, done)
 
-                (cache, tok, pos, done, ok), (toks, dones) = jax.lax.scan(
-                    step, (cache, tok, pos, done, ok),
-                    jnp.arange(chunk, dtype=jnp.int32))
+                with jax.named_scope("decode_lane"):
+                    (cache, tok, pos, done, ok), (toks, dones) = \
+                        jax.lax.scan(step, (cache, tok, pos, done, ok),
+                                     jnp.arange(chunk, dtype=jnp.int32))
                 n_valid = jnp.full(tok.shape, chunk, jnp.int32)
             # cache-finiteness guard: quantized (integer-code) matmul paths
             # launder NaN activations into finite garbage codes, so poisoned
@@ -928,11 +935,12 @@ class Engine:
             # be all-reduced over the model axis — the ok out-spec is
             # model-replicated and an unreduced miss on the clean shards
             # would mask the poisoned one.
-            cache_ok = _cache_finite(cache)
-            axis = tp_lib.model_axis()
-            if axis is not None:
-                cache_ok = jax.lax.pmin(
-                    cache_ok.astype(jnp.int32), axis).astype(bool)
+            with jax.named_scope("guard"):
+                cache_ok = _cache_finite(cache)
+                axis = tp_lib.model_axis()
+                if axis is not None:
+                    cache_ok = jax.lax.pmin(
+                        cache_ok.astype(jnp.int32), axis).astype(bool)
             ok = ok & cache_ok
             return (cache, tok, pos, done, tok0, done0, toks.T, dones.T, ok,
                     n_valid)

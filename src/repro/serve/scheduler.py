@@ -83,7 +83,7 @@ from __future__ import annotations
 import collections
 import math
 import warnings
-from typing import Deque, List, Optional, Sequence
+from typing import Deque, List, NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -95,6 +95,22 @@ from repro.serve.faults import CacheCorruption, EngineFault, InjectedFault
 from repro.serve.request import Request, RequestStatus
 
 _UNSET = object()
+
+# host spans of a round, on the profiler's clock (one TraceMe check each when
+# no trace is being taken): serve.step holds serve.admin, serve.assemble,
+# serve.dispatch, serve.wait and serve.deliver
+_span = jax.profiler.TraceAnnotation
+
+
+class _Round(NamedTuple):
+    """What a round's assembly decided, for its dispatch and delivery."""
+    entries: Optional[dict]     # chunk-lane arrays, None without prefill work
+    plan: dict                  # slot -> chunk-lane progress after the round
+    fresh: list                 # (slot, request) admitted this round
+    completing: set             # slots whose last prompt token lands now
+    C: int                      # chunk-lane width (0 without prefill work)
+    greedy: bool
+    spec: bool
 
 
 class Scheduler:
@@ -739,21 +755,24 @@ class Scheduler:
         snapshot, admit into free slots, decode one chunk, retire finished
         sequences.  Returns the number of useful tokens emitted this round
         (0 on a recovered fault — the retry replays next round)."""
-        now_v = now() if callable(now) else now
-        self._expire_deadlines(now_v)
-        self._shed_overload(now_v)
-        if self.snapshot_interval and \
-                self._ticks % self.snapshot_interval == 0:
-            self._snap = self.snapshot()
-            self._submit_log.clear()
-        self._ticks += 1
-        try:
-            emitted = self._step_inner(now, now_v)
-        except EngineFault as err:
-            self._recover(err, now_v)
-            return 0
-        self._retries_since_progress = 0
-        return emitted
+        with jax.profiler.StepTraceAnnotation("serve.step",
+                                              step_num=self._ticks):
+            now_v = now() if callable(now) else now
+            with _span("serve.admin"):
+                self._expire_deadlines(now_v)
+                self._shed_overload(now_v)
+                if self.snapshot_interval and \
+                        self._ticks % self.snapshot_interval == 0:
+                    self._snap = self.snapshot()
+                    self._submit_log.clear()
+            self._ticks += 1
+            try:
+                emitted = self._step_inner(now, now_v)
+            except EngineFault as err:
+                self._recover(err, now_v)
+                return 0
+            self._retries_since_progress = 0
+            return emitted
 
     def _assemble_chunk(self, allow_admission: bool):
         """Build this round's chunk-lane entries: continue mid-prefill slots
@@ -863,12 +882,34 @@ class Scheduler:
         return entries, plan, fresh, completing, parks
 
     def _step_inner(self, now, now_v) -> int:
+        with _span("serve.assemble"):
+            rnd = self._assemble_round(now, now_v)
+        if rnd is None:
+            return 0
+        scfg = self.engine.scfg
+        with _span("serve.dispatch"):
+            outs = self._dispatch_round(rnd)
+            # spec rounds burn draft_k draft + draft_k+1 verify sampling
+            # streams
+            self._step += rnd.C + (2 * scfg.draft_k + 1 if rnd.spec
+                                   else self.chunk)
+        _, _, toks, _, ok, _ = outs
+        with _span("serve.wait"):
+            # the one place the host waits on the round's program: its
+            # outputs become ready together, so later reads find them
+            first_h = np.asarray(ok if scfg.guards else toks)
+        with _span("serve.deliver"):
+            return self._deliver_round(rnd, outs, first_h, now)
+
+    def _assemble_round(self, now, now_v) -> Optional[_Round]:
+        """Admit, map pages and build the chunk lane; park fresh rows and
+        decide the round's program variant.  None when no slot is live."""
         entries, plan, fresh, completing = None, {}, [], set()
         parks: dict = {}
         if self.engine.requires_monolithic_admission:
             self._admit(now)
             if not any(r is not None for r in self.slots):
-                return 0
+                return None
             if self.engine.paged:
                 # block accounting: map pages for the chunk ahead; preempts
                 # most-slack/youngest-first when the pool is exhausted
@@ -889,7 +930,7 @@ class Scheduler:
             entries, plan, fresh, completing, parks = \
                 self._assemble_chunk(allow)
         if not any(r is not None for r in self.slots):
-            return 0
+            return None
         C = self.engine.prefill_chunk if entries is not None else 0
         if parks:
             # freshly admitted rows must park at their first entry BEFORE
@@ -931,12 +972,18 @@ class Scheduler:
                 if held > lim:
                     use_spec = False
                     break
+        return _Round(entries, plan, fresh, completing, C, greedy, use_spec)
+
+    def _dispatch_round(self, rnd: _Round) -> tuple:
+        """Enqueue the round's one program; returns its outputs (tok0,
+        done0, toks, dones, ok, n_valid) without waiting on them."""
+        fresh = rnd.fresh
         try:
             (self.cache, self.tok, self.pos, self.done, tok0, done0, toks,
              dones, ok, n_valid) = self.engine.step(
-                self.cache, entries, self.tok, self.pos, self.done, self.eos,
-                self.temperature, self.top_k, self.top_p, self._step,
-                self.chunk, greedy=greedy, spec=use_spec)
+                self.cache, rnd.entries, self.tok, self.pos, self.done,
+                self.eos, self.temperature, self.top_k, self.top_p,
+                self._step, self.chunk, greedy=rnd.greedy, spec=rnd.spec)
         except InjectedFault:
             # the dispatch never ran: roll back this round's fresh chunk
             # admissions (pages released, candidates back at the queue head
@@ -955,16 +1002,22 @@ class Scheduler:
                 # restore the free-slot sentinel the parks overwrote
                 self._free_on_device([slot for slot, _ in fresh])
             raise
-        # spec rounds burn draft_k draft + draft_k+1 verify sampling streams
-        self._step += C + (2 * scfg.draft_k + 1 if use_spec else self.chunk)
-        if self.engine.scfg.guards:
-            ok_h = np.asarray(ok)
-            if not ok_h.all():
-                # poisoned logits never reach a streaming callback:
-                # detection precedes every emit below
-                raise CacheCorruption(
-                    "non-finite logits in decode for slots "
-                    f"{np.flatnonzero(~ok_h).tolist()}")
+        return tok0, done0, toks, dones, ok, n_valid
+
+    def _deliver_round(self, rnd: _Round, outs: tuple, first_h: np.ndarray,
+                       now) -> int:
+        """Commit the chunk lane and the stats, emit the round's tokens,
+        retire finished requests.  ``first_h`` is the host copy of the
+        round's first read output: ``ok`` with guards on, else ``toks``."""
+        entries, plan, fresh, completing, C, _, use_spec = rnd
+        tok0, done0, toks, dones, _, n_valid = outs
+        scfg = self.engine.scfg
+        if scfg.guards and not first_h.all():
+            # poisoned logits never reach a streaming callback:
+            # detection precedes every emit below
+            raise CacheCorruption(
+                "non-finite logits in decode for slots "
+                f"{np.flatnonzero(~first_h).tolist()}")
         # commit the chunk lane: progress applied, freshly covered pages
         # become prefix-shareable, admission bookkeeping recorded
         for slot, p in plan.items():
@@ -982,7 +1035,8 @@ class Scheduler:
         self.stats["rounds"] += 1
         self.stats["occupancy_sum"] += (
             sum(r is not None for r in self.slots) / self.n_slots)
-        toks_h, dones_h = np.asarray(toks), np.asarray(dones)
+        toks_h = np.asarray(toks) if scfg.guards else first_h
+        dones_h = np.asarray(dones)
         tok0_h, done0_h = np.asarray(tok0), np.asarray(done0)
         nv_h = np.asarray(n_valid)
         if use_spec:

@@ -9,17 +9,20 @@ activations) and ``w[k,n]`` (4-bit weights) the accumulator is
              = sum_{k,b} bit_b(a[m,k]) * TW[(k,b), n]            (b = 0..3)
     TW[(k,b), n] = T[w[k,n], 2^b]          (a lookup: one-hot in w[k,n])
 
-Per block, each weight code selects its four power-of-two partial products
-``T[w, 2^b]`` from the product table with a 4-level select tree on its
-bits (the table sits in SMEM; the activation-code-8 column carries the top
-bit's sign, so signed vs unsigned activations is purely a table-layout
-choice), then bitplaned activation nibbles select-and-reduce over K on the
-MXU (a [bm, 4*bk] x [4*bk, bn] dot with int32 accumulation).
-Multiplication is still performed by *selection from the product table* —
-the faithful LUT semantics: every partial product is a table entry, never
-computed.  On TPU the dot is int8 (every operand value fits int8); its MAC
-count is 4x an int8 matmul (the price of selection), and the selection
-costs 15 selects per weight code and bit on the VPU, independent of M.
+The four power-of-two partial products ``T[w, 2^b]`` of a weight code each
+fit int8, so they travel packed in one int32 word, byte b = ``T[w, 2^b]``
+(``pack_partial_products``; 16 words in SMEM).  Per block, each weight
+code selects its word with ONE 4-level select tree on its bits, and a
+shift and an int8 cast take each byte out into the TW operand (the
+activation-code-8 column carries the top bit's sign, so signed vs unsigned
+activations is purely a table-layout choice); then bitplaned activation
+nibbles select-and-reduce over K on the MXU (a [bm, 4*bk] x [4*bk, bn] dot
+with int32 accumulation).  Multiplication is still performed by
+*selection from the product table* — the faithful LUT semantics: every
+partial product is a table entry, never computed.  On TPU the dot is int8
+(every operand value fits int8); its MAC count is 4x an int8 matmul (the
+price of selection), and the selection costs 15 selects per weight code
+on the VPU (plus the bit tests, 3 shifts and the casts), independent of M.
 The serial per-row gather loop is kept as ``lutmul_gather``.
 
 ``lutmul_tmac``: the second formulation — T-MAC/BitNet-style *weight-plane*
@@ -69,15 +72,16 @@ multiplication mechanism.
 Block shapes are MXU/VPU aligned: (bm, bk, bn) multiples of (8, 128, 128);
 the defaults keep the per-block VMEM footprint under ~2 MB:
   a tile      bm*bk            (uint8)
-  a one-hot   bm*bk*16         (int8)
+  a bitplanes bm*bk*4          (int8)
   w tile      bk*bn/2          (uint8, packed)
-  TW tile     bk*16*bn         (int8)
+  TW tile     bk*4*bn          (int8)
   acc tile    bm*bn*4          (int32)
-  table       16*16 int8/int32
+  table       16*16 int32 (gather) / 16 packed int32 words (one-hot)
 """
 from __future__ import annotations
 
 import functools
+import operator
 
 import jax
 import jax.numpy as jnp
@@ -107,15 +111,31 @@ def _select_tree(bits, leaves):
     return leaves[0]
 
 
-def _onehot_contract(a: jax.Array, wp: jax.Array, t2,
+def pack_partial_products(table) -> jax.Array:
+    """[16] int32: word c holds weight code c's four power-of-two partial
+    products from the [16, 16] product table, byte b = ``T[c, 2^b]`` (two's
+    complement; byte 3 lands in the sign bit and wraps).  A constant table
+    folds to a constant under jit."""
+    t = jnp.asarray(table, jnp.int32)
+    return functools.reduce(operator.or_, [(t[:, 1 << b] & 0xFF) << (8 * b)
+                                           for b in range(4)])
+
+
+def _byte(x: jax.Array, b: int) -> jax.Array:
+    """Byte b of every int32 word as int8 (the narrowing cast keeps the low
+    byte, so its sign comes with it)."""
+    return (x >> (8 * b) if b else x).astype(jnp.int8)
+
+
+def _onehot_contract(a: jax.Array, wp: jax.Array, leaves,
                      contract_dtype) -> jax.Array:
     """One block of the LUT contraction (module docstring).
 
     a: [bm, bk] int32 codes; wp: [bk//2, bn] packed codes (byte k2 holds
-    w[2*k2] in its low nibble, w[2*k2+1] in its high one); t2: [16, 16]
-    int32 product table (row = weight code, col = activation code) — an
-    SMEM ref in the kernel, any indexable array elsewhere.  Returns the
-    int32 [bm, bn] partial accumulator.
+    w[2*k2] in its low nibble, w[2*k2+1] in its high one); leaves: [16]
+    int32 ``pack_partial_products`` words (index = weight code) — an SMEM
+    ref in the kernel, any indexable array elsewhere.  Returns the int32
+    [bm, bn] partial accumulator.
 
     ``contract_dtype``: int8 on the TPU path (an MXU-native int8 dot with
     int32 accumulation — every value involved fits int8); float32 in
@@ -126,13 +146,13 @@ def _onehot_contract(a: jax.Array, wp: jax.Array, t2,
     pref = jnp.float32 if contract_dtype == jnp.float32 else jnp.int32
     wp = wp.astype(jnp.int32)
     # selection stage, on the two nibble halves (even k, odd k) so no
-    # sublane interleave is needed: TW rows are ordered (b, parity, k2)
-    halves = [[(w >> j) & 1 == 1 for j in range(4)]
-              for w in (wp & 0xF, wp >> 4)]
-    tw = jnp.concatenate(
-        [_select_tree(bits, [t2[c, 1 << b] for c in range(16)])
-         for b in range(4) for bits in halves],
-        axis=0).astype(contract_dtype)                          # [4*bk, bn]
+    # sublane interleave is needed: one select tree per half picks each
+    # code's packed word, whose bytes become TW rows ordered (b, parity, k2)
+    words = [_select_tree([wp & (1 << (4 * h + j)) != 0 for j in range(4)],
+                          [leaves[c] for c in range(16)])
+             for h in range(2)]
+    tw = jnp.concatenate([_byte(x, b) for b in range(4) for x in words],
+                         axis=0).astype(contract_dtype)         # [4*bk, bn]
     # accumulation stage: activation codes reordered to (parity, k2) by an
     # exact 0/1 permutation dot, bitplaned b-major to match TW's rows —
     # the MXU only ever selects and sums table entries
@@ -203,15 +223,18 @@ def lutmul_pallas(a_codes: jax.Array, w_packed: jax.Array, table: jax.Array,
     N = w_packed.shape[1]
     grid = (M // bm, N // bn, K // bk)
     cd = jnp.float32 if interpret else jnp.int8
-    body = (functools.partial(_lutmul_onehot_body, contract_dtype=cd)
-            if impl == "onehot" else _lutmul_gather_body)
+    if impl == "onehot":
+        body = functools.partial(_lutmul_onehot_body, contract_dtype=cd)
+        table = pack_partial_products(table)
+    else:
+        body = _lutmul_gather_body
     return pl.pallas_call(
         body,
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
             pl.BlockSpec((bk // 2, bn), lambda i, j, k: (k, j)),
-            pl.BlockSpec(memory_space=pltpu.SMEM),      # product table
+            pl.BlockSpec(memory_space=pltpu.SMEM),      # (packed) table
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.int32),
@@ -448,7 +471,7 @@ def lutmul_fused_pallas(a_codes: jax.Array, w_packed: jax.Array,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
             pl.BlockSpec((bk // 2, bn), lambda i, j, k: (k, j)),
-            pl.BlockSpec(memory_space=pltpu.SMEM),      # product table
+            pl.BlockSpec(memory_space=pltpu.SMEM),      # packed table
             pl.BlockSpec((bm, 1), lambda i, j, k: (i, 0)),
             pl.BlockSpec((1, bn), lambda i, j, k: (0, j)),
         ],
@@ -457,7 +480,7 @@ def lutmul_fused_pallas(a_codes: jax.Array, w_packed: jax.Array,
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
         interpret=interpret,
         name="lutmul_onehot_fused",
-    )(a_codes, w_packed, table, a_scale, w_scale)
+    )(a_codes, w_packed, pack_partial_products(table), a_scale, w_scale)
 
 
 def _lutmul_tmac_fused_body(*refs, nk: int, out_dtype, coeffs, const, g,

@@ -65,7 +65,7 @@ def _case(name):
     return fn, jnp.int8, w, fused
 
 
-@pytest.mark.parametrize("M", [8, 128])
+@pytest.mark.parametrize("M", [8, 16, 128])
 @pytest.mark.parametrize("name", [
     "onehot_fused", "tmac_w4_g1_fused", "tmac_w4_g2_fused",
     "tmac_ternary_g2_fused", "tmac_w4_g2_raw", "int8_fused"])

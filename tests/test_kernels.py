@@ -6,8 +6,8 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro.core.lut import pack_int4
-from repro.kernels.lutmul import ops, ref
+from repro.core.lut import contraction_table, pack_int4
+from repro.kernels.lutmul import kernel, ops, ref
 
 SHAPES = [(8, 32, 16), (16, 128, 128), (100, 256, 130), (128, 384, 256),
           (1, 64, 48), (257, 128, 64)]
@@ -194,6 +194,65 @@ def test_fused_kernel_matches_scaled_oracle():
         a_p, w_p, _ops._get_table(True), as_p, ws_p, bm=16, bn=128, bk=128,
         out_dtype=jnp.float32, interpret=True)[:M, :N]
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def _four_tree_contract(a, wp, table):
+    """The one-hot contraction as one select tree per weight code AND
+    activation bit (four per code), on the unpacked table: TW rows ordered
+    (b, parity, k2), activation bitplanes to match, summed in int64."""
+    halves = [[(w >> j) & 1 == 1 for j in range(4)]
+              for w in (wp & 0xF, wp >> 4)]
+    tw = np.concatenate(
+        [np.asarray(kernel._select_tree(
+            [jnp.asarray(x) for x in bits],
+            [int(table[c, 1 << b]) for c in range(16)]))
+         for b in range(4) for bits in halves], axis=0).astype(np.int64)
+    a_pk = np.concatenate([a[:, 0::2], a[:, 1::2]], axis=1)
+    a_bits = np.concatenate([(a_pk >> b) & 1 for b in range(4)], axis=1)
+    return a_bits.astype(np.int64) @ tw
+
+
+def _every_code_pair_block(rng, bm=16, bk=256, bn=128):
+    """Codes where every (activation code, weight code) pair meets in the
+    contraction of every output, in both nibble halves."""
+    a = (np.arange(bm)[:, None] + np.arange(bk)[None, :]) % 16
+    w = (np.arange(bk)[:, None] // 2 + np.arange(bn)[None, :]) % 16
+    a, w = a[rng.permutation(bm)], w[:, rng.permutation(bn)]
+    wp = (w[0::2] | (w[1::2] << 4)).astype(np.int32)
+    return a.astype(np.int32), wp
+
+
+def _extreme_table(rng):
+    """A random int8 table with -128 and 127 in every byte position of the
+    packed words (the partial-product columns 1, 2, 4, 8)."""
+    t = rng.integers(-128, 128, (16, 16))
+    cols = [1, 2, 4, 8]
+    t[0, cols], t[15, cols] = -128, 127
+    t[5, cols], t[10, cols] = [-128, 127, -128, 127], [127, -128, 127, -128]
+    return t
+
+
+@pytest.mark.parametrize("contract_dtype", ["float32", "int8"])
+@pytest.mark.parametrize("table_kind", ["signed", "unsigned", "random"])
+def test_onehot_contract_packed_words_exact(table_kind, contract_dtype):
+    """One select tree per weight code over its four partial products
+    packed in an int32 word returns the same int32 accumulators as four
+    per-bit trees over the table, and as the oracle for real tables."""
+    rng = np.random.default_rng(14)
+    a, wp = _every_code_pair_block(rng)
+    table = (_extreme_table(rng) if table_kind == "random" else
+             contraction_table(a_signed=table_kind == "signed"))
+    got = np.asarray(kernel._onehot_contract(
+        jnp.asarray(a), jnp.asarray(wp),
+        kernel.pack_partial_products(table),
+        contract_dtype=jnp.dtype(contract_dtype)))
+    assert got.dtype == np.int32
+    np.testing.assert_array_equal(got, _four_tree_contract(a, wp, table))
+    if table_kind != "random":
+        want = ref.lutmul_ref(jnp.asarray(a.astype(np.uint8)),
+                              jnp.asarray(wp.astype(np.uint8)),
+                              a_signed=table_kind == "signed")
+        np.testing.assert_array_equal(got, np.asarray(want))
 
 
 def test_prequant_malformed_packed_rejected_on_all_backends():

@@ -87,7 +87,8 @@ def test_fuzz_onehot_contract_dtype_exact(blocks, contract_dtype, seed):
     a = _codes(rng, bm, bk).astype(np.int32)
     wp = _packed_weights(rng, bk, bn)
     table = jnp.asarray(contraction_table(a_signed=True), jnp.int32)
-    acc = kernel._onehot_contract(jnp.asarray(a), jnp.asarray(wp), table,
+    acc = kernel._onehot_contract(jnp.asarray(a), jnp.asarray(wp),
+                                  kernel.pack_partial_products(table),
                                   contract_dtype=jnp.dtype(contract_dtype))
     want = ref.lutmul_ref(jnp.asarray(a.astype(np.uint8)), jnp.asarray(wp))
     np.testing.assert_array_equal(np.asarray(acc), np.asarray(want))

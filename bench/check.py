@@ -12,17 +12,20 @@ program's place (``bench.reference``), which some number must fail:
                 the reference's best logit at that position.  Greedy
                 serving puts the best token first, so a sound run reads
                 only rounding; a wrong token, cache or kernel reads O(1).
-    kv_gap      cache rows: over a sample of occupied slots, the largest
-                |K - K_ref| and |V - V_ref| the program wrote for the
-                positions it has fed, relative to the largest |K_ref| or
-                |V_ref| of the same layer.  Rows a batched prefill wrote
-                are compared with the reference's batched form, the rest
-                with its one-position-at-a-time form.
-    kv_gap_layer0  the same, of the first layer alone: its K/V rows come
+    kv_gap      cache rows: over a sample of slots, the largest
+                difference between the rows the program wrote for the
+                positions it has fed and the reference's, relative to the
+                largest reference value of the same layer and row name.
+                The architecture names the rows (``cache_rows`` and its
+                reference step: K and V for a dense decoder).  Rows a
+                batched prefill wrote are compared with the reference's
+                batched form, the rest with its one-position-at-a-time
+                form.
+    kv_gap_layer0  the same, of the first layer alone: its rows come
                 straight from the embedding through one norm and one
                 projection, so rounding differences that later layers
-                amplify have not yet grown, while a K/V cache held in a
-                lower precision shows at full size.
+                amplify have not yet grown, while a cache held in a lower
+                precision shows at full size.
 
 Both run the reference over the same prompts and served tokens, teacher
 forced, once the window has closed and the program's state is freed.
@@ -44,11 +47,12 @@ class Sample:
     state is freed."""
     requests: list       # (prompt, served tokens, positions batch-prefilled)
     slots: list          # (tokens fed so far, positions batch-prefilled)
-    kv: tuple = ()       # the slots' K and V rows, device arrays
-                         # [n_layers, slots, P, Hkv, D], P as _pad gives
+    kv: dict = dataclasses.field(default_factory=dict)
+                         # the slots' cache rows by name, device arrays
+                         # [n_layers, slots, P, ...], P as _pad gives
 
 
-def take_sample(win, sched, check: dict, seed: int) -> Sample:
+def take_sample(win, sched, cfg: dict, check: dict, seed: int) -> Sample:
     from bench import serve
     n = int(check["sample"])
     reqs = []
@@ -56,16 +60,16 @@ def take_sample(win, sched, check: dict, seed: int) -> Sample:
         for r in serve.sample_requests(win, n, seed):
             reqs.append((list(r.prompt), list(r.tokens),
                          win.mono.get(id(r), 0)))
-    slots, kv = [], ()
+    slots, kv = [], {}
     if any(name.startswith("kv_gap") for name in check["numbers"]):
-        rows = serve.sample_slots(sched, n, seed)
+        rows = serve.sample_slots(win, sched, n, seed)
         for s, r, written in rows:
             seq = (list(r.prompt) + list(r.tokens))[:written]
             slots.append((seq, win.mono.get(id(r), 0)))
         if rows:
             P = _pad([s for s, _ in slots], 1, sched.engine.scfg.max_len
                      ).shape[1]
-            kv = serve.cache_rows(sched, [s for s, _, _ in rows], P)
+            kv = serve.cache_rows(cfg, sched, [s for s, _, _ in rows], P)
     return Sample(reqs, slots, kv)
 
 
@@ -135,19 +139,21 @@ def logit_gap(cfg: dict, seed: int, sample: Sample, rows: int, T: int,
 @jax.jit
 def _rel_gap(got, want, n):
     """Largest |got - want| over each slot's first ``n`` rows, relative to
-    the largest |want| there, per layer: [n_layers, slots]."""
-    P = got.shape[2]
-    keep = (jnp.arange(P)[None, :] < n[:, None])[None, :, :, None, None]
-    g, w = got.astype(jnp.float32), want.astype(jnp.float32)
-    num = jnp.max(jnp.where(keep, jnp.abs(g - w), 0.0), axis=(2, 3, 4))
-    den = jnp.max(jnp.where(keep, jnp.abs(w), 0.0), axis=(2, 3, 4))
+    the largest |want| there, per layer: [n_layers, slots].  Rows are
+    [n_layers, slots, P, ...]."""
+    L, S, P = got.shape[:3]
+    keep = (jnp.arange(P)[None, :] < n[:, None])[None, :, :, None]
+    g = got.astype(jnp.float32).reshape(L, S, P, -1)
+    w = want.astype(jnp.float32).reshape(L, S, P, -1)
+    num = jnp.max(jnp.where(keep, jnp.abs(g - w), 0.0), axis=(2, 3))
+    den = jnp.max(jnp.where(keep, jnp.abs(w), 0.0), axis=(2, 3))
     return num / jnp.maximum(den, 1e-30)
 
 
 def kv_gaps(cfg: dict, seed: int, sample: Sample, rows: int, T: int,
             control: str = ""):
-    """{"k": [n_layers], "v": [n_layers]}: the largest relative difference
-    of each layer's K and V rows over the sampled slots; with ``control``
+    """{row name: [n_layers]}: the largest relative difference of each
+    layer's rows of that name over the sampled slots; with ``control``
     that lower-precision reference stands in for the program."""
     if not sample.slots:
         return None
@@ -161,17 +167,20 @@ def kv_gaps(cfg: dict, seed: int, sample: Sample, rows: int, T: int,
         P = tokens.shape[1]
         zero = np.zeros((tokens.shape[0], 1), np.int32)
         ref = reference.run(cfg, seed, tokens, mono=mono, T=T, at=zero,
-                            score=zero, kv_rows=P)
+                            score=zero, kv_rows=P)["rows"]
         if control:
             got = reference.run(cfg, seed, tokens, mono=mono, T=T, at=zero,
-                                score=zero, kv_rows=P, lower=control)
-            got = [got[name][:, :len(idx)] for name in ("k", "v")]
+                                score=zero, kv_rows=P, lower=control)["rows"]
+            got = {name: g[:, :len(idx)] for name, g in got.items()}
         else:
             sel = jnp.asarray(idx)
-            got = [kv[:, sel, :P] for kv in sample.kv]
+            got = {name: g[:, sel, :P] for name, g in sample.kv.items()}
+        if set(got) != set(ref):
+            raise ValueError(f"the program's cache rows {sorted(got)} are "
+                             f"not the reference's {sorted(ref)}")
         n = jnp.asarray([len(s) for s in seqs], jnp.int32)
-        for name, g in zip(("k", "v"), got):
-            gap = np.asarray(_rel_gap(g, ref[name][:, :len(idx)], n)).max(
+        for name, want in ref.items():
+            gap = np.asarray(_rel_gap(got[name], want[:, :len(idx)], n)).max(
                 axis=1)
             worst[name] = np.maximum(worst.get(name, gap), gap)
     return worst
@@ -179,9 +188,9 @@ def kv_gaps(cfg: dict, seed: int, sample: Sample, rows: int, T: int,
 
 def kv_gap_by_layer(cfg: dict, seed: int, sample: Sample, rows: int,
                     T: int, control: str = ""):
-    """The larger of the K and V gaps of each layer, [n_layers]."""
+    """The largest gap of each layer over its row names, [n_layers]."""
     gaps = kv_gaps(cfg, seed, sample, rows, T, control)
-    return None if gaps is None else np.maximum(gaps["k"], gaps["v"])
+    return None if gaps is None else np.max(list(gaps.values()), axis=0)
 
 
 def kv_gap(cfg: dict, seed: int, sample: Sample, rows: int, T: int,

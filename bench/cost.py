@@ -5,14 +5,15 @@ do: a matmul of M real rows by a K x N weight is ``2 M K N`` integer
 operations whatever rows a kernel pads to, and reads its weight codes once.
 Integer operations (every quantized projection and the head) are held
 against the chip's int8 peak, attention's bfloat16 operations against its
-bf16 peak, and bytes against HBM bandwidth.
+bf16 peak, and bytes against HBM bandwidth.  What one forward of a model
+needs is its architecture's (``kernel_calls`` and ``forward`` of
+``bench/archs/<arch>.py``).
 """
 from __future__ import annotations
 
 import dataclasses
 
-from bench.weights import HEAD_FORMAT as HEAD
-from bench.weights import dims, projections, weight_format
+from bench import spec
 
 
 @dataclasses.dataclass
@@ -42,18 +43,8 @@ def matmul(M: int, K: int, N: int, bits: int) -> Work:
 
 
 def kernel_calls(cfg: dict, kernel: str) -> list:
-    """(K, N, code bits) of each call of ``kernel`` in one forward: the
-    body projections run on their format's ``KERNEL``, the head on the
-    head format's."""
-    m = dims(cfg)
-    body, head = weight_format(cfg, m["format"]), weight_format(cfg, HEAD)
-    out = []
-    if body.KERNEL == kernel:
-        out += [(K, N, body.BITS)
-                for _, _, K, N, _ in projections(cfg)] * m["L"]
-    if head.KERNEL == kernel:
-        out.append((m["d"], m["V"], head.BITS))
-    return out
+    """(K, N, code bits) of each call of ``kernel`` in one forward."""
+    return spec.arch(cfg).kernel_calls(cfg, kernel)
 
 
 def kernel_work(cfg: dict, kernel: str, M: int) -> Work:
@@ -64,41 +55,10 @@ def kernel_work(cfg: dict, kernel: str, M: int) -> Work:
     return w
 
 
-def weight_bytes(cfg: dict) -> float:
-    """Bytes of the served weights one forward reads: body codes and
-    scales, norms, biases, the head (the embedding is gathered by row)."""
-    m = dims(cfg)
-    bits = weight_format(cfg, m["format"]).BITS
-    body = sum(K * N * bits / 8 + 4 * N
-               for _, _, K, N, _ in projections(cfg))
-    body += 2 * 4 * m["d"]
-    if m["qkv_bias"]:
-        body += 4 * (m["h"] + 2 * m["kv"]) * m["hd"]
-    head = weight_format(cfg, HEAD).BITS
-    return (m["L"] * body + m["d"] * m["V"] * head / 8
-            + 4 * m["V"] + 4 * m["d"])
-
-
-def kv_bytes_per_token(cfg: dict) -> int:
-    """bf16 K and V of one token over all layers."""
-    m = dims(cfg)
-    return m["L"] * 2 * m["kv"] * m["hd"] * 2
-
-
 def forward(cfg: dict, ctx: list) -> Work:
     """One forward of the rows whose context lengths (the position each
-    row attends from, plus one) are ``ctx``: every weight read once, each
-    row's projections and head, its attention over its own context."""
-    m = dims(cfg)
-    rows = len(ctx)
-    if not rows:
-        return Work()
-    per_row = 2.0 * (m["L"] * sum(K * N for _, _, K, N, _ in
-                                  projections(cfg)) + m["d"] * m["V"])
-    attn = sum(4.0 * m["L"] * m["h"] * m["hd"] * c for c in ctx)
-    return Work(int_ops=rows * per_row, bf16_ops=attn,
-                bytes=weight_bytes(cfg) + kv_bytes_per_token(cfg) * sum(ctx)
-                + rows * 4 * m["d"])           # + embedding rows gathered
+    row attends from, plus one) are ``ctx``."""
+    return spec.arch(cfg).forward(cfg, ctx)
 
 
 def decode_round(cfg: dict, live: list, chunk: int) -> Work:

@@ -2,26 +2,13 @@
 ``ModelConfig``, and the engine and scheduler the window drives."""
 from __future__ import annotations
 
-from bench.weights import dims
+from bench import spec
 
 
 def model_config(cfg: dict):
-    """The program's ModelConfig for a LLaMA-family dense decoder file."""
-    from repro.models.transformer import BlockSpec, ModelConfig
-    m = dims(cfg)
-    if cfg["hidden_act"] != "silu":
-        raise ValueError(f"{cfg['name']}: only SwiGLU (silu) MLPs are "
-                         f"wired here, got {cfg['hidden_act']!r}")
-    if float(cfg["rms_norm_eps"]) != 1e-6:
-        raise ValueError(f"{cfg['name']}: the program's RMSNorm runs "
-                         f"eps=1e-6, the file says {cfg['rms_norm_eps']}")
-    return ModelConfig(
-        name=cfg["name"], family="dense", n_layers=m["L"], d_model=m["d"],
-        n_heads=m["h"], n_kv=m["kv"], head_dim=m["hd"], d_ff=m["ff"],
-        vocab=m["V"], qkv_bias=m["qkv_bias"],
-        pattern=(BlockSpec(kind="attn", attn_type="global", mlp="swiglu"),),
-        rope_theta=m["theta"], quant=m["quant"],
-        compute_dtype=cfg["serving"]["compute_dtype"])
+    """The program's ModelConfig, as the configuration's architecture
+    builds it."""
+    return spec.arch(cfg).model_config(cfg)
 
 
 # keys of a mix's ``serve`` that go to the Scheduler, not to ServeConfig

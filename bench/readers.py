@@ -46,3 +46,37 @@ def idle_share(ctx):
     if hi <= lo:
         return None
     return pct(1.0 - tr.busy_per_device(ctx.trace) / (hi - lo))
+
+
+def lane_spans(ctx, chunk_lane: bool) -> list:
+    """(start, end) in trace nanoseconds of each traced round of the lane
+    (chunk-lane rounds, or decode-only ones)."""
+    return [span for r, _, span in ctx.traced_round_spans()
+            if r.chunk_lane == chunk_lane]
+
+
+def host_ms(ctx, chunk_lane: bool):
+    """Mean host time a round of the lane spent in ``Scheduler.step``
+    outside its one blocking read: the program's ``serve.admin``,
+    ``serve.assemble``, ``serve.dispatch`` and ``serve.deliver`` spans
+    inside each round, ms.  Silent where the program wrote none."""
+    from bench import scopes
+    spans = lane_spans(ctx, chunk_lane)
+    if not spans:
+        return None
+    per = [sum(r[p] for p in scopes.HOST_PHASES)
+           for r in scopes.phase_ms(ctx.trace, spans)]
+    return sum(per) / len(per) if any(per) else None
+
+
+def scope_share(ctx, chunk_lane: bool, keep):
+    """% of the lane's rounds' device busy time in ops for which ``keep``
+    (name, (scope path, kernel name)) holds.  Silent where no op of those
+    rounds carries a scope or a kernel name (the trace's ops were not
+    joined to their programs)."""
+    from bench import scopes
+    spans = lane_spans(ctx, chunk_lane)
+    if not spans or not sum(scopes.busy_each(
+            ctx.trace, lambda n, s: scopes.scoped(s), spans)):
+        return None
+    return scopes.share(ctx.trace, spans, keep)

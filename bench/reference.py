@@ -1,11 +1,12 @@
 """Plain reference of the served model, independent of the program.
 
-A LLaMA/Qwen2-family decoder written out in ``jax.numpy``: RMSNorm, GQA
-attention with rotate-half RoPE, SwiGLU MLP, every projection an integer
-matmul of per-token absmax activation codes with the weight codes, then the
-per-token and per-channel scales.  It imports nothing of the program and
-takes nothing the program made: the weight codes are regenerated layer by
-layer from the seed (``bench.weights``).
+The model written out in ``jax.numpy``: the embedding, each layer by its
+architecture's step (``layer`` of ``bench/archs/<arch>.py``, built from
+the pieces here), the final RMSNorm and the int8 head.  Every projection
+is an integer matmul of per-token absmax activation codes with the weight
+codes, then the per-token and per-channel scales (``linear``).  It imports
+nothing of the program and takes nothing the program made: the weight
+codes are regenerated layer by layer from the seed (``bench.weights``).
 
 It computes at the precision the configuration file states: activations
 and the KV cache in bfloat16, norms, RoPE and softmax in float32, integer
@@ -14,18 +15,12 @@ matters because every projection re-quantizes its input to 4 (or 8) bits:
 a one-ulp difference flips an activation code, and random layers amplify a
 flipped code to O(1) within a few layers.
 
-Attention is evaluated in the two forms the server uses, chosen per
-position: positions admitted by the batched prefill (``mono`` leading
-positions) attend over exactly the prompt's keys; every later position
-attends, as a decode step does, one position at a time over a ``max_len``
-key buffer masked past its own position.  (Batched over positions, the
-same sums ran in another order on the chip and a qwen2-7b seed read a gap
-of 0.03125; one position at a time it reads 0.)
-
 The control is the same model one precision step below what the
-configuration states (``lower``): ``"kv_fp8"`` rounds keys and values to
-float8 (e4m3) where bfloat16 is stated; ``"act_int4"`` quantizes the body
-projections' activations to int4 where int8 is stated.
+configuration states (``lower``, a step the architecture's layer takes,
+such as ``"kv_fp8"`` or ``"act_int4"``).  ``mono`` (the leading positions
+a batched prefill admitted) and ``T`` (``max_len``) tell the step which
+form of attention the server ran at each position
+(``bench.archs.dense_decoder.attention``).
 """
 from __future__ import annotations
 
@@ -37,7 +32,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench import weights
+from bench import spec, weights
 
 NEG_INF = -1e30
 COMPILE = {"xla_allow_excess_precision": False}
@@ -101,55 +96,17 @@ def attend(q, k, v, q_pos, k_pos):
     return out.reshape(B, S, Hq, D)
 
 
-@functools.partial(jax.jit, static_argnames=("cfg_key", "mono", "T",
-                                             "lower"),
+@functools.partial(jax.jit, static_argnames=("cfg_key", "kind", "mono",
+                                             "T", "lower"),
                    compiler_options=COMPILE)
-def _layer(x, g, key, *, cfg_key: str, mono: int, T: int, lower: str):
+def _layer(x, g, key, *, cfg_key: str, kind: str, mono: int, T: int,
+           lower: str):
+    """Layer ``g`` (a layer of ``kind``): the architecture's step over the
+    leaves regenerated from the seed."""
     cfg = json.loads(cfg_key)
-    m = weights.dims(cfg)
-    cd = x.dtype
-    abits = 4 if lower == "act_int4" else m["abits"]
-    fmt = weights.weight_format(cfg, m["format"])
-    eps = float(cfg["rms_norm_eps"])
-    p = weights.layer_params(cfg, key, g)
-    B, L, _ = x.shape
-    pos = jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32)[None], (B, L))
-
-    h = rms_norm(x, p["ln1"]["scale"], eps)
-    a = p["attn"]
-    q = linear(h, a["wq"], abits, cd, fmt).reshape(B, L, m["h"], m["hd"])
-    k = linear(h, a["wk"], abits, cd, fmt).reshape(B, L, m["kv"], m["hd"])
-    v = linear(h, a["wv"], abits, cd, fmt).reshape(B, L, m["kv"], m["hd"])
-    q = rope(q, pos, m["theta"])
-    k = rope(k, pos, m["theta"])
-    if lower == "kv_fp8":
-        k = k.astype(jnp.float8_e4m3fn).astype(cd)
-        v = v.astype(jnp.float8_e4m3fn).astype(cd)
-    outs = []
-    if mono:
-        outs.append(attend(q[:, :mono], k[:, :mono], v[:, :mono],
-                           pos[:, :mono], pos[:, :mono]))
-    if L > mono:
-        pad = ((0, 0), (0, T - L), (0, 0), (0, 0))
-        kT, vT = jnp.pad(k, pad), jnp.pad(v, pad)
-        tpos = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None], (B, T))
-
-        def one(_, p):               # one query position, as a decode step
-            qp = jax.lax.dynamic_slice_in_dim(q, p, 1, axis=1)
-            return None, attend(qp, kT, vT, jnp.full((B, 1), p), tpos)[:, 0]
-
-        _, o = jax.lax.scan(one, None, jnp.arange(mono, L))
-        outs.append(jnp.moveaxis(o, 0, 1))
-    out = jnp.concatenate(outs, axis=1) if len(outs) > 1 else outs[0]
-    x = x + linear(out.astype(cd).reshape(B, L, -1), a["wo"], abits, cd,
-                   fmt)
-
-    h = rms_norm(x, p["ln2"]["scale"], eps)
-    f = p["mlp"]
-    y = jax.nn.silu(linear(h, f["wg"], abits, cd, fmt)) * linear(
-        h, f["wi"], abits, cd, fmt)
-    x = x + linear(y, f["wo"], abits, cd, fmt)
-    return x, k, v
+    p = weights.layer_params(cfg, key, g, kind)
+    return spec.arch(cfg).layer(cfg, kind, p, x, mono=mono, T=T,
+                                lower=lower)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg_key",),
@@ -185,23 +142,22 @@ def run(cfg: dict, seed: int, tokens: np.ndarray, *, mono: int, T: int,
 
     Returns host arrays: ``best``, ``got`` (logit of ``score`` [B, P] at
     positions ``at`` [B, P]) and ``argmax``; with ``kv_rows`` > 0 also
-    ``k``/``v`` [n_layers, B, kv_rows, Hkv, D] of the first positions, on
-    the device."""
+    ``rows``, the cache rows the architecture's step names, each
+    [n_layers, B, kv_rows, ...] of the first positions, on the device."""
     ck = json.dumps(cfg, sort_keys=True)
-    m = weights.dims(cfg)
     key = weights.seed_key(seed)
     x = _embed(jnp.asarray(tokens, jnp.int32), key, cfg_key=ck)
-    ks, vs = [], []
-    for g in range(m["L"]):
-        x, k, v = _layer(x, jnp.uint32(g), key, cfg_key=ck, mono=mono, T=T,
-                         lower=lower)
+    rows: dict = {}
+    for g, kind in enumerate(spec.arch(cfg).layout(cfg).kinds()):
+        x, r = _layer(x, jnp.uint32(g), key, cfg_key=ck, kind=kind,
+                      mono=mono, T=T, lower=lower)
         if kv_rows:
-            ks.append(k[:, :kv_rows])
-            vs.append(v[:, :kv_rows])
+            for name, a in r.items():
+                rows.setdefault(name, []).append(a[:, :kv_rows])
     best, got, arg = _head(x, jnp.asarray(at, jnp.int32),
                            jnp.asarray(score, jnp.int32), key, cfg_key=ck)
     out = {"best": np.asarray(best), "got": np.asarray(got),
            "argmax": np.asarray(arg)}
     if kv_rows:
-        out["k"], out["v"] = jnp.stack(ks), jnp.stack(vs)
+        out["rows"] = {name: jnp.stack(a) for name, a in rows.items()}
     return out

@@ -24,6 +24,7 @@ import time
 T_START = time.perf_counter()
 
 import argparse  # noqa: E402
+import functools  # noqa: E402
 import gc  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
@@ -35,7 +36,7 @@ ROOT = Path(__file__).resolve().parents[1]
 if str(ROOT) not in sys.path:
     sys.path.insert(0, str(ROOT))
 
-from bench import check, spec  # noqa: E402
+from bench import check, scopes, spec  # noqa: E402
 from bench import trace as tr_mod  # noqa: E402
 from bench.spec import SpecError  # noqa: E402
 
@@ -49,16 +50,18 @@ class NoChip(RuntimeError):
 class Context:
     """What a metric reader sees (``bench/metrics/<name>.py``)."""
 
-    def __init__(self, cell, window, setup_s, trace, peaks, slots, chunk):
+    def __init__(self, cell, window, setup_s, trace, peaks, slots, chunk,
+                 cache_shapes):
         from bench import cost
         self.cell = cell
         self.config = cell.config
         self.window = window            # bench.serve.Window
         self.setup_s = setup_s
-        self.trace = trace              # bench.trace.Trace or None
+        self.trace = trace              # bench.scopes.ScopedTrace or None
         self.peaks = peaks
         self.slots = slots
         self.chunk = chunk
+        self.cache_shapes = cache_shapes  # bench.serve.cache_shapes
         self.cost = cost
         self.kernel_names = json.loads(
             (spec.bench_dir(cell.root) / "kernels.json").read_text())
@@ -67,16 +70,27 @@ class Context:
     def elapsed_s(self) -> float:
         return self.window.t1 - self.window.t0
 
+    @functools.cached_property
+    def _traced(self) -> list:
+        if self.trace is None:
+            return []
+        spans = self.trace.rounds
+        if len(spans) != len(self.window.rounds):
+            return []
+        busy = tr_mod.round_busy_ns(self.trace)
+        return [(r, b / 1e9, span)
+                for r, b, span in zip(self.window.rounds, busy, spans)]
+
     def traced_rounds(self) -> list:
         """(Round, device busy seconds inside it) for every window round
         the trace holds (host spans and window rounds pair in order)."""
-        from bench import trace as tr
-        if self.trace is None:
-            return []
-        busy = tr.round_busy_ns(self.trace)
-        if len(busy) != len(self.window.rounds):
-            return []
-        return [(r, b / 1e9) for r, b in zip(self.window.rounds, busy)]
+        return [(r, b) for r, b, _ in self._traced]
+
+    def traced_round_spans(self) -> list:
+        """(Round, device busy seconds, its ``bench.round`` span (start,
+        end) in trace nanoseconds) for every window round the trace
+        holds."""
+        return list(self._traced)
 
 
 def _init_jax(root: Path):
@@ -190,13 +204,14 @@ def run_cell(root, workload: str, seed: int, seconds: float, trace: bool, *,
     tr = None
     if trace:
         jax.profiler.stop_trace()
-        tr = tr_mod.load(trace_dir)
+        tr = scopes.load(trace_dir)
         shutil.rmtree(trace_dir, ignore_errors=True)
 
     stats = [d.memory_stats() or {} for d in devs]
     mem_peak = max(s.get("peak_bytes_in_use", 0) for s in stats)
-    sample = check.take_sample(win, sched, cell.check, seed)
+    sample = check.take_sample(win, sched, cell.config, cell.check, seed)
     slots, chunk = sched.n_slots, sched.chunk
+    cache_shapes = serve.cache_shapes(sched)
     attempted = sum(1 for r in win.requests if r.tokens or r.done
                     or r.slot is not None)
     failed = sum(1 for r in win.requests
@@ -214,7 +229,7 @@ def run_cell(root, workload: str, seed: int, seconds: float, trace: bool, *,
     results["compiles_in_window"] = (compiles, 0)
     correct = check.verdict(results)
 
-    ctx = Context(cell, win, setup_s, tr, peaks, slots, chunk)
+    ctx = Context(cell, win, setup_s, tr, peaks, slots, chunk, cache_shapes)
     metrics = {}
     for m in (cell.per_layer if trace else cell.end_to_end):
         value = spec.load_reader(root, m["name"])(ctx)

@@ -24,9 +24,10 @@ the loop holding them.
 
 prints a JSON summary: host time by phase a round, device time by scope
 and by kernel name, the busy time no name claims, and the idle gaps, each
-labelled by the innermost host span under it.  The benchmark's per-layer
-readers do not see these names: ``bench/trace.py`` keeps only the
-benchmark's spans (PERF.md, section 7).
+labelled by the innermost host span under it.  ``bench/run.py`` loads
+every traced run with ``load``, so a metric reader finds the spans and
+each op's scope in ``ctx.trace`` and reads them with ``share``,
+``busy_each`` and ``phase_ms``.
 """
 from __future__ import annotations
 

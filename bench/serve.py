@@ -12,7 +12,7 @@ import time
 
 import numpy as np
 
-from bench import traffic
+from bench import spec, traffic
 
 
 @dataclasses.dataclass
@@ -39,6 +39,7 @@ class Window:
                                  # by the batched prefill
     stats0: dict
     stats1: dict
+    held: dict                   # slot -> request, at the last round's start
 
 
 def _annotate(name: str):
@@ -92,6 +93,7 @@ def measure(sched, gen, reqs: list, mono: dict, seconds: float) -> Window:
         if r.tokens:
             last[id(r)] = t0
     while True:
+        held = {s: r for s, r in enumerate(sched.slots) if r is not None}
         decoding = [len(r.prompt) + len(r.tokens)
                     for s, r in enumerate(sched.slots) if r is not None
                     and sched._progress[s] >= sched._target[s]]
@@ -132,7 +134,7 @@ def measure(sched, gen, reqs: list, mono: dict, seconds: float) -> Window:
         if b - t0 >= seconds:
             break
     return Window(t0, b, rounds, gaps, reqs, mono, stats0,
-                  dict(sched.stats))
+                  dict(sched.stats), held)
 
 
 # -- what the check compares ---------------------------------------------
@@ -152,14 +154,20 @@ def sample_requests(win: Window, n: int, seed: int) -> list:
     return [longest] + [rest[i] for i in sorted(pick)]
 
 
-def sample_slots(sched, n: int, seed: int) -> list:
-    """(slot, request, positions written) of up to ``n`` occupied slots
-    whose cache rows hold prompt positions, drawn from the seed."""
+def sample_slots(win: Window, sched, n: int, seed: int) -> list:
+    """(slot, request, positions written) of up to ``n`` slots whose cache
+    rows hold a request's fed positions at the window's close, drawn from
+    the seed: the occupied slots, and those whose request finished in the
+    window's last round (no round has run since, so its rows stand)."""
     rows = []
-    for s, r in enumerate(sched.slots):
+    for s in range(sched.n_slots):
+        r = sched.slots[s]
         if r is None:
-            continue
-        if sched._progress[s] < sched._target[s]:
+            r = win.held.get(s)
+            if r is None or not r.done:
+                continue
+            written = len(r.prompt) + len(r.tokens) - 1
+        elif sched._progress[s] < sched._target[s]:
             written = sched._progress[s]
         else:
             written = len(r.prompt) + len(r.tokens) - 1
@@ -171,11 +179,18 @@ def sample_slots(sched, n: int, seed: int) -> list:
     return [rows[i] for i in pick]
 
 
-def cache_rows(sched, slots: list, P: int) -> tuple:
-    """Device copies of the K and V rows the program wrote for ``slots``,
-    the first ``P`` positions of each: [n_layers, len(slots), P, Hkv, D]
-    in the cache's dtype."""
+def cache_rows(cfg: dict, sched, slots: list, P: int) -> dict:
+    """Device copies of the cache rows the program wrote for ``slots``, the
+    first ``P`` positions of each, named as the configuration's
+    architecture names them: {name: [n_layers, len(slots), P, ...]}."""
     import jax.numpy as jnp
-    cache = sched.cache[0]
-    idx = jnp.asarray(slots, jnp.int32)
-    return tuple(cache[key][:, idx, :P] for key in ("k", "v"))
+    return spec.arch(cfg).cache_rows(sched.cache,
+                                     jnp.asarray(slots, jnp.int32), P)
+
+
+def cache_shapes(sched) -> set:
+    """Shapes of the program's cache leaves, each whole and one layer of
+    it (the leading axis dropped)."""
+    import jax
+    return {shape for leaf in jax.tree_util.tree_leaves(sched.cache)
+            for shape in (tuple(leaf.shape), tuple(leaf.shape[1:]))}

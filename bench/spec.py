@@ -3,7 +3,13 @@
 Everything that belongs to one configuration, traffic mix, metric or check
 lives in a file of its own, named after it:
 
-    bench/configs/<config>.json     model sizes as run, source, cuts
+    bench/configs/<config>.json     model sizes as run, source, cuts; its
+                                    ``arch`` names the architecture module
+    bench/archs/<arch>.py           everything one architecture's layers
+                                    need: the program's ModelConfig, the
+                                    served leaves, the reference step, the
+                                    work of a forward, the cache rows the
+                                    check compares (``bench/archs/__init__``)
     bench/traffic/<traffic>.json    traffic parameters for bench.traffic
     bench/traffic_kinds/<kind>.py   how requests of a mix's ``kind`` go out
     bench/formats/<format>.py       a stored weight-code format, named by a
@@ -17,9 +23,13 @@ so a new cell, format, traffic kind or metric is added with files and a
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib.util
 import json
 from pathlib import Path
+
+
+BENCH = Path(__file__).resolve().parent
 
 
 class SpecError(Exception):
@@ -66,6 +76,21 @@ def load_module(bench: Path, folder: str, name: str):
     return mod
 
 
+@functools.lru_cache(maxsize=None)
+def _arch_module(bench: str, name: str):
+    return load_module(Path(bench), "archs", name)
+
+
+def arch(cfg: dict):
+    """The architecture module ``archs/<cfg["arch"]>.py`` of the benchmark
+    tree the configuration was loaded from (``bench_dir``; default: this
+    one).  There is no default architecture."""
+    name = cfg.get("arch")
+    if not isinstance(name, str) or not name:
+        raise SpecError(f"configuration {cfg.get('name')!r} names no arch")
+    return _arch_module(str(cfg.get("bench_dir", BENCH)), name)
+
+
 def applies(metric: dict, workload: str) -> bool:
     """A metric without a ``workloads`` list is reported by every cell."""
     return "workloads" not in metric or workload in metric["workloads"]
@@ -87,8 +112,9 @@ def load_cell(root, workload: str) -> Cell:
     config = _load_json(root / centry["file"])
     config.setdefault("name", centry["name"])
     bd = bench_dir(root)
-    # where the configuration's weight format file is found
+    # where the configuration's architecture and weight format are found
     config["bench_dir"] = str(bd)
+    arch(config)
     traffic = _load_json(bd / "traffic" / f"{w['traffic']}.json")
     load_module(bd, "traffic_kinds", traffic.get("kind", ""))
     load_module(bd, "formats", config["serving"]["format"])

@@ -225,3 +225,41 @@ def test_a_traced_serving_loop_holds_the_programs_spans(tmp_path):
         assert [s[0] for s in kids] == list(scopes.PHASES)
         assert all(step[1] <= s[1] and s[2] <= step[2] for s in kids)
     assert all(h > 0 for h in scopes.summary(t)["host_ms"])
+
+
+def _ctx(tr):
+    """A run's reader context over ``tr``: round 1 carries the chunk lane,
+    round 2 is decode-only."""
+    import types
+
+    from bench.run import Context
+    from bench.serve import Round, Window
+    from bench.tests.tiny import REPO
+    rounds = [Round(0.0, 1.0, 8, True, 8, [], list(range(8))),
+              Round(1.0, 2.0, 0, False, 16, [10] * 2, [])]
+    win = Window(0.0, 2.0, rounds, [], [], {}, {}, {}, {})
+    cell = types.SimpleNamespace(root=REPO, config={})
+    return Context(cell, win, 1.0, tr, None, 2, 8, CACHE)
+
+
+@pytest.mark.parametrize("name,want", [
+    ("host_ms.prefill", (10 + 30 + 50 + 40) / 1e6),
+    ("host_ms.decode", (5 + 10 + 30 + 30) / 1e6),
+    ("decode_lane_share.prefill", 100 * 500 / 700),
+    ("kv_share.decode", 100 * 200 / 350)])
+def test_readers_of_the_scoped_trace(name, want):
+    from bench.spec import load_reader
+    from bench.tests.tiny import REPO
+    assert load_reader(REPO, name)(_ctx(_recorded())) == pytest.approx(want)
+
+
+@pytest.mark.parametrize("name", ["decode_lane_share.prefill",
+                                  "kv_share.decode"])
+def test_share_readers_stay_silent_without_scopes(name):
+    """A trace whose ops were not joined to their programs reads nothing,
+    not 0."""
+    from bench.spec import load_reader
+    from bench.tests.tiny import REPO
+    bare = _recorded()
+    bare.scopes = {}
+    assert load_reader(REPO, name)(_ctx(bare)) is None

@@ -9,7 +9,7 @@ import types
 import numpy as np
 import pytest
 
-from bench import cost, peaks, trace, traffic
+from bench import cost, peaks, spec, trace, traffic
 from bench.serve import Round, Window
 from bench.spec import load_reader
 from bench.tests.tiny import REPO
@@ -48,8 +48,9 @@ def test_qwen_kernel_counts_by_hand():
 def test_qwen_step_counts_by_hand():
     d, V, L = 3584, 152064, 28
     kv_tok = L * 2 * 4 * 128 * 2
-    assert cost.kv_bytes_per_token(QWEN) == 57344 == kv_tok
-    body = cost.weight_bytes(QWEN)
+    dense = spec.arch(QWEN)
+    assert dense.kv_bytes_per_token(QWEN) == 57344 == kv_tok
+    body = dense.weight_bytes(QWEN)
     # 5,995,134,976 bytes of served params minus the f32 embedding,
     # which a forward gathers by row
     assert body == 5995134976 - 4 * V * d
@@ -98,7 +99,7 @@ def _window():
               "admitted_tokens": 8}
     stats1 = {"rounds": 8, "occupancy_sum": 3.5, "prefill_tokens": 24,
               "admitted_tokens": 20}
-    return Window(0.0, 4.0, rounds, gaps, [], {}, stats0, stats1)
+    return Window(0.0, 4.0, rounds, gaps, [], {}, stats0, stats1, {})
 
 
 def _ctx(win, tr=None):
@@ -116,6 +117,30 @@ def _ctx(win, tr=None):
     ("chunk_fill.prefill", 100 * 12 / 16)])
 def test_window_arithmetic(name, want):
     assert load_reader(REPO, name)(_ctx(_window())) == pytest.approx(want)
+
+
+def test_sample_keeps_slots_freed_in_the_last_round():
+    """A slot whose request finished in the window's last round still holds
+    that request's rows (no round ran since), so a window that closes with
+    every slot free still has rows to compare; a request that left its
+    slot unfinished (preempted) does not count."""
+    from bench.serve import sample_slots
+
+    def req(prompt, tokens, done):
+        return types.SimpleNamespace(prompt=[1] * prompt,
+                                     tokens=[2] * tokens, done=done)
+    finished, decoding, preempted = req(4, 2, True), req(4, 3, False), \
+        req(6, 0, False)
+    sched = types.SimpleNamespace(n_slots=3, slots=[None, decoding, None],
+                                  _progress=[0, 4, 0], _target=[0, 4, 0])
+    win = _window()
+    win.held = {0: finished, 1: decoding, 2: preempted}
+    assert sample_slots(win, sched, 8, 3) == [(0, finished, 5),
+                                              (1, decoding, 6)]
+    sched.slots = [None] * 3
+    decoding.done = True
+    assert sample_slots(win, sched, 8, 3) == [(0, finished, 5),
+                                              (1, decoding, 6)]
 
 
 # op names as a v5e trace records them: the instruction's HLO text

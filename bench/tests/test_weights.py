@@ -9,7 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench import weights
+from bench import spec, weights
 from bench.tests.tiny import REPO
 
 CONFIGS = ["qwen2-7b.w4a4_lut", "bitnet-b1.58-3b.ternary_a8_tmac"]
@@ -17,11 +17,7 @@ CONFIGS = ["qwen2-7b.w4a4_lut", "bitnet-b1.58-3b.ternary_a8_tmac"]
 
 def _small(name: str) -> dict:
     cfg = json.loads((REPO / f"bench/configs/{name}.json").read_text())
-    gqa = cfg["num_key_value_heads"] < cfg["num_attention_heads"]
-    cfg.update(hidden_size=64, intermediate_size=128, num_attention_heads=4,
-               num_key_value_heads=2 if gqa else 4, num_hidden_layers=3,
-               vocab_size=512)
-    return cfg
+    return spec.arch(cfg).tiny(cfg, 3)
 
 
 @pytest.mark.parametrize("name", CONFIGS)
@@ -48,8 +44,9 @@ def test_reference_decodes_codes_as_the_program(name):
     params = weights.served_params(cfg, seed)
     key = weights.seed_key(seed)
     fmt = weights.weight_format(cfg, cfg["serving"]["format"])
+    kind, = spec.arch(cfg).layout(cfg).pattern
     for g in (0, 2):
-        layer = weights.layer_params(cfg, key, jnp.uint32(g))
+        layer = weights.layer_params(cfg, key, jnp.uint32(g), kind)
         stacked = jax.tree_util.tree_map(lambda x: x[g],
                                          params["blocks"][0])
         for group, proj in (("attn", "wq"), ("mlp", "wo")):

@@ -1,7 +1,8 @@
 """A tiny copy of the benchmark tree, for driving whole runs on the CPU.
 
 Every configuration keeps its architecture and serving mode at a few
-layers of width 64; every traffic mix keeps its shape (clients, start,
+layers, shrunk as its architecture module's ``tiny`` says (width 64 for
+a dense decoder); every traffic mix keeps its shape (clients, start,
 closed loop) at a few slots and short prompts.  The program under test is
 the repository's own ``src``.
 """
@@ -14,6 +15,8 @@ from pathlib import Path
 from unittest import mock
 
 import jax  # noqa: F401  (read its environment before ``run`` patches it)
+
+from bench.spec import load_module
 
 REPO = Path(__file__).resolve().parents[2]
 
@@ -28,11 +31,8 @@ def make(dst: Path, layers: int = 2, limits: dict | None = None) -> Path:
     for c in spec["configs"]:
         p = dst / c["file"]
         cfg = json.loads(p.read_text())
-        gqa = cfg["num_key_value_heads"] < cfg["num_attention_heads"]
-        cfg.update(hidden_size=64, intermediate_size=128,
-                   num_attention_heads=4, num_key_value_heads=2 if gqa else 4,
-                   num_hidden_layers=layers, vocab_size=512)
-        p.write_text(json.dumps(cfg))
+        arch = load_module(dst / "bench", "archs", cfg["arch"])
+        p.write_text(json.dumps(arch.tiny(cfg, layers)))
     for p in (dst / "bench" / "traffic").glob("*.json"):
         mix = json.loads(p.read_text())
         mix["clients"] = min(mix["clients"], 4)

@@ -7,15 +7,15 @@ HLO text (``%copy.121 = bf16[...] copy(...)``).  A ``while`` or
 ``conditional`` event spans the ops of its body, so those containers are
 dropped and only leaf ops count.  The benchmark's host spans
 (``bench.round``, ``bench.clients``) sit on the host plane, on the same
-clock.  Everything here works on plain (name, start_ns, end_ns) tuples, so
-it is tested on a small recorded trace without a chip.
+clock.  ``bench.scopes.load`` reads a trace directory into a
+``ScopedTrace``, this ``Trace`` with the program's own names added.
+Everything here works on plain (name, start_ns, end_ns) tuples, so it is
+tested on a small recorded trace without a chip.
 """
 from __future__ import annotations
 
 import dataclasses
-import glob
 import re
-from pathlib import Path
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 OPS_LINE = "XLA Ops"
@@ -37,33 +37,6 @@ class Trace:
     def window(self) -> tuple:
         r = self.rounds
         return (r[0][0], r[-1][1]) if r else (0, 0)
-
-
-def load(trace_dir) -> Trace:
-    from jax.profiler import ProfileData
-    files = glob.glob(str(Path(trace_dir) / "**" / "*.xplane.pb"),
-                      recursive=True)
-    if len(files) != 1:
-        raise FileNotFoundError(f"expected one xplane.pb under {trace_dir}, "
-                                f"found {len(files)}")
-    data = ProfileData.from_file(files[0])
-    ops, spans = {}, []
-    for plane in data.planes:
-        if DEVICE_PLANE.match(plane.name):
-            for line in plane.lines:
-                if line.name == OPS_LINE:
-                    ops[plane.name] = [(e.name, e.start_ns,
-                                        e.start_ns + e.duration_ns)
-                                       for e in line.events
-                                       if not CONTAINER.match(e.name)]
-        elif plane.name.startswith("/host:"):
-            for line in plane.lines:
-                for e in line.events:
-                    if e.name in HOST_SPANS:
-                        spans.append((e.name, e.start_ns,
-                                      e.start_ns + e.duration_ns))
-    spans.sort(key=lambda s: s[1])
-    return Trace(ops, spans)
 
 
 def union(intervals, lo: float, hi: float) -> list:
@@ -132,8 +105,9 @@ def label(name: str, kernels: dict) -> str:
 def breakdown(trace: Trace, kernels: dict, top: int = 10) -> dict:
     """The device ops that took the most time in the window (summed by
     instruction, so a loop body's op sums over its iterations), and the
-    longest idle gaps labelled by the host span under the gap's
-    midpoint."""
+    longest idle gaps, each labelled by the innermost host span under its
+    midpoint (``bench.scopes.idle_gaps``)."""
+    from bench.scopes import idle_gaps
     lo, hi = trace.window
     by_name: dict = {}
     for ev in trace.ops.values():
@@ -143,16 +117,5 @@ def breakdown(trace: Trace, kernels: dict, top: int = 10) -> dict:
                 by_name[key] = by_name.get(key, 0) + (b - a)
     n = max(len(trace.ops), 1)
     ops = sorted(by_name.items(), key=lambda kv: -kv[1])[:top]
-    gaps = []
-    for ev in trace.ops.values():
-        merged = union(((a, b) for _, a, b in ev), lo, hi)
-        edges = [lo] + [x for ab in merged for x in ab] + [hi]
-        for a, b in zip(edges[0::2], edges[1::2]):
-            if b > a:
-                mid = (a + b) / 2
-                span = next((s for s, x, y in trace.spans if x <= mid < y),
-                            "no host span")
-                gaps.append((span, (b - a) / 1e9))
-    gaps.sort(key=lambda g: -g[1])
     return {"device_ops": [[k, v / n / 1e9] for k, v in ops],
-            "idle_gaps": [[k, v] for k, v in gaps[:top]]}
+            "idle_gaps": [[k, v] for k, v in idle_gaps(trace, lo, hi)[:top]]}

@@ -132,6 +132,8 @@ def cache_specs(cache_sds, rules: Rules, mesh: Mesh | None = None) -> Any:
 # "h" / rwkv "S" recurrent states must NOT match)
 KV_CACHE_LEAVES = frozenset({"k", "v", "shared_k", "shared_v", "xk", "xv"})
 KV_SCALE_LEAVES = frozenset({"k_scale", "v_scale"})
+# MLA's per-token latent rows [.., B, T, r]: no head axis to shard
+LATENT_CACHE_LEAVES = frozenset({"c_kv", "k_pe"})
 
 
 def _leaf_key(path) -> str:

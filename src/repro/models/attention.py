@@ -4,7 +4,14 @@ Gemma-2 logit soft-capping, cross-attention, and KV-cache decode.
 Blocked attention keeps the score tensor at [.., q_block, kv_block] so 32k
 prefill fits in HBM; the online-softmax recurrence is the standard
 FlashAttention algorithm expressed in lax.scan (XLA fuses it well on TPU; a
-Pallas flash kernel is a beyond-paper optimization tracked in EXPERIMENTS.md).
+Pallas flash kernel would be a beyond-paper optimization).
+
+Multi-head latent attention (DeepSeek-V2/V3 MLA, ``init_mla``,
+``mla_attention``, ``mla_decode_attention``) caches one compressed latent
+``c_kv`` and one shared RoPE key ``k_pe`` per token instead of per-head K/V:
+prefill expands them into per-head keys and values, decode folds the
+up-projection into the query (the absorbed form) and attends over the
+latent cache directly.
 """
 from __future__ import annotations
 
@@ -27,7 +34,7 @@ def init_attention(key, d_model: int, n_heads: int, n_kv: int, head_dim: int,
     """``split_heads=True`` stores projections as [d, H, dh] (3D) so the
     head axis is a real param dim — sharding then never straddles a reshape
     boundary (kills GSPMD's involuntary resharding permutes when
-    H % mesh != 0; see EXPERIMENTS.md §Perf)."""
+    H % mesh != 0)."""
     kq, kk, kv, ko = jax.random.split(key, 4)
     if not split_heads:
         return {
@@ -182,7 +189,7 @@ def full_attention(q, k, v, q_pos, k_pos, *, causal=True, window=None,
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bshgk,bkhd->bshgd", p.astype(v.dtype), v,
                      preferred_element_type=jnp.float32)
-    return out.reshape(B, S, Hq, D)
+    return out.reshape(B, S, Hq, v.shape[-1])
 
 
 def attention(p: Params, x: jax.Array, positions: jax.Array, *,
@@ -551,3 +558,142 @@ def cross_attention(p: Params, x: jax.Array, enc: jax.Array, *,
     out = full_attention(q, k, v, q_pos, k_pos, causal=False)
     return linear(p["wo"], out.reshape(B, S, -1).astype(compute_dtype),
                   quant, compute_dtype)
+
+
+# ---------------------------------------------------------------------------
+# multi-head latent attention (DeepSeek-V2/V3 MLA).  Per token the cache
+# holds c_kv (the kv_a projection's first kv_lora_rank outputs after their
+# RMSNorm) and k_pe (its last qk_rope_dim outputs after RoPE, one key shared
+# by every head).  wkv_b maps c_kv to each head's [nope key | value]; it is
+# a float leaf, so the expanded form (prefill) and the absorbed form
+# (decode) are the same function of c_kv.
+# ---------------------------------------------------------------------------
+
+def init_mla(key, d_model: int, n_heads: int, mla, dtype=jnp.float32
+             ) -> Params:
+    """``mla``: a ``transformer.MLAConfig`` (kv_lora_rank, qk_nope_dim,
+    qk_rope_dim, v_head_dim).  ``wq`` has no low-rank factorisation
+    (``q_lora_rank`` null) and no projection has a bias."""
+    kq, ka, kb, ko = jax.random.split(key, 4)
+    r, nope, rope, dv = (mla.kv_lora_rank, mla.qk_nope_dim, mla.qk_rope_dim,
+                         mla.v_head_dim)
+    return {
+        "wq": init_linear(kq, d_model, n_heads * (nope + rope), dtype=dtype),
+        "wkv_a": init_linear(ka, d_model, r + rope, dtype=dtype),
+        "kv_norm": {"scale": jnp.ones((r,), dtype)},
+        "wkv_b": init_linear(kb, r, n_heads * (nope + dv), dtype=dtype),
+        "wo": init_linear(ko, n_heads * dv, d_model, dtype=dtype),
+    }
+
+
+def deinterleave(x: jax.Array) -> jax.Array:
+    """[..., D] pairs (x0, x1), (x2, x3), ... -> [evens | odds]: the order
+    DeepSeek's ``apply_rotary_pos_emb`` puts the rope dims in before its
+    rotate-half RoPE."""
+    D = x.shape[-1]
+    return jnp.swapaxes(x.reshape(x.shape[:-1] + (D // 2, 2)), -1, -2
+                        ).reshape(x.shape)
+
+
+def mla_project(p: Params, x: jax.Array, positions: jax.Array, *,
+                n_heads: int, mla, rope_theta: float, norm_eps: float,
+                quant: str, compute_dtype):
+    """x [B, S, d] -> (q_nope [B,S,H,nope], q_pe [B,S,H,rope] after RoPE,
+    c_kv [B,S,r] after its norm, k_pe [B,S,rope] after RoPE)."""
+    from repro.models.layers import rms_norm
+    B, S, _ = x.shape
+    r, nope = mla.kv_lora_rank, mla.qk_nope_dim
+    q = linear(p["wq"], x, quant, compute_dtype).reshape(B, S, n_heads, -1)
+    q_nope, q_pe = q[..., :nope], q[..., nope:]
+    kv = linear(p["wkv_a"], x, quant, compute_dtype)
+    c_kv = rms_norm(p["kv_norm"], kv[..., :r], norm_eps)
+    q_pe = apply_rope(deinterleave(q_pe), positions, rope_theta)
+    k_pe = apply_rope(deinterleave(kv[..., r:])[:, :, None], positions,
+                      rope_theta)[:, :, 0]
+    return q_nope, q_pe, c_kv, k_pe
+
+
+def _kv_b(p: Params, mla, n_heads: int, cd):
+    """wkv_b split per head: (W_UK [r, H, nope], W_UV [r, H, v])."""
+    w = p["wkv_b"]["w"].astype(cd).reshape(mla.kv_lora_rank, n_heads, -1)
+    return w[..., :mla.qk_nope_dim], w[..., mla.qk_nope_dim:]
+
+
+def mla_attention(p: Params, x: jax.Array, positions: jax.Array, *,
+                  n_heads: int, mla, rope_theta: float, norm_eps: float,
+                  quant: str = "none", compute_dtype=jnp.bfloat16,
+                  return_cache: bool = False):
+    """Causal MLA over a full sequence (train / prefill), expanded form:
+    per-head keys [k_nope | k_pe] (qk dim nope + rope) and values from
+    ``wkv_b``, softmax scale 1/sqrt(nope + rope).  The batch rows attend
+    one at a time (each row's sums are the same), so a long batched
+    prefill holds one row's keys, values and scores.  ``return_cache``
+    also returns the latent rows {"c_kv", "k_pe"} the decode cache
+    holds."""
+    cd = compute_dtype
+    B, S, _ = x.shape
+    q_nope, q_pe, c_kv, k_pe = mla_project(
+        p, x, positions, n_heads=n_heads, mla=mla, rope_theta=rope_theta,
+        norm_eps=norm_eps, quant=quant, compute_dtype=cd)
+    w_uk, w_uv = _kv_b(p, mla, n_heads, cd)
+    q = jnp.concatenate([q_nope, q_pe], axis=-1)
+
+    def rows(q, c, kp, pos):
+        k_nope = jnp.einsum("bsr,rhd->bshd", c, w_uk,
+                            preferred_element_type=jnp.float32).astype(cd)
+        v = jnp.einsum("bsr,rhd->bshd", c, w_uv,
+                       preferred_element_type=jnp.float32).astype(cd)
+        k = jnp.concatenate([k_nope, jnp.broadcast_to(
+            kp[:, :, None], k_nope.shape[:3] + kp.shape[-1:])], axis=-1)
+        return full_attention(q, k, v, pos, pos, causal=True)
+
+    out = jax.lax.map(lambda a: rows(*(t[None] for t in a))[0],
+                      (q, c_kv, k_pe, positions))
+    y = linear(p["wo"], out.astype(cd).reshape(B, S, -1), quant, cd)
+    if return_cache:
+        return y, {"c_kv": c_kv.astype(cd), "k_pe": k_pe.astype(cd)}
+    return y
+
+
+def mla_decode_attention(p: Params, x: jax.Array, cache: dict,
+                         pos: jax.Array, *, n_heads: int, mla,
+                         rope_theta: float, norm_eps: float,
+                         quant: str = "none", compute_dtype=jnp.bfloat16):
+    """One decode step over the latent cache, absorbed form.  x [B, 1, d];
+    cache {"c_kv": [B, T, r], "k_pe": [B, T, rope]}; pos scalar or [B]
+    (negative: a free slot, every key masked).
+
+    W_UK folds into the query (q_lat = q_nope W_UK^T, [B, H, r]); scores
+    are q_lat . c_kv + q_pe . k_pe times 1/sqrt(nope + rope); the softmax
+    reads c_kv back (the latent readout, [B, H, r]), then W_UV and wo.
+    Returns (y, cache)."""
+    cd = compute_dtype
+    B = x.shape[0]
+    T = cache["c_kv"].shape[1]
+    posv = _pos_vec(pos, B)
+    posb = posv[:, None]
+    q_nope, q_pe, c_kv, k_pe = mla_project(
+        p, x, posb, n_heads=n_heads, mla=mla, rope_theta=rope_theta,
+        norm_eps=norm_eps, quant=quant, compute_dtype=cd)
+    slot = jnp.clip(posv, 0, T - 1)
+    with jax.named_scope("kv_write"):
+        cache = {"c_kv": _write_kv_slot(cache["c_kv"], c_kv, slot),
+                 "k_pe": _write_kv_slot(cache["k_pe"], k_pe, slot)}
+    with jax.named_scope("mla"):
+        w_uk, w_uv = _kv_b(p, mla, n_heads, cd)
+        scale = 1.0 / math.sqrt(mla.qk_nope_dim + mla.qk_rope_dim)
+        q_lat = jnp.einsum("bhd,rhd->bhr", q_nope[:, 0], w_uk,
+                           preferred_element_type=jnp.float32).astype(cd)
+        s = (jnp.einsum("bhr,btr->bht", q_lat, cache["c_kv"],
+                        preferred_element_type=jnp.float32)
+             + jnp.einsum("bhe,bte->bht", q_pe[:, 0], cache["k_pe"],
+                          preferred_element_type=jnp.float32)) * scale
+        keep = _mask(posb, decode_kv_positions(posv, T, False), True, None)
+        s = jnp.where(keep, s, NEG_INF)          # keep [B, 1, T]
+        prob = jax.nn.softmax(s, axis=-1)
+        o_lat = jnp.einsum("bht,btr->bhr", prob.astype(cd), cache["c_kv"],
+                           preferred_element_type=jnp.float32).astype(cd)
+        out = jnp.einsum("bhr,rhd->bhd", o_lat, w_uv,
+                         preferred_element_type=jnp.float32).astype(cd)
+    y = linear(p["wo"], out.reshape(B, 1, -1), quant, cd)
+    return y, cache

@@ -1,12 +1,17 @@
 """Decoder-LM assembly: pattern-based blocks, scanned over repeated groups.
 
-A model is ``embed -> scan(groups) -> final_norm -> lm_head`` where one group
-is one repetition of ``cfg.pattern`` (e.g. Gemma-2: (local, global) x 13;
-Zamba2: (mamba x 6 + shared attn at position 0) x 9; RWKV6: (rwkv,) x 24).
-Scanning over groups keeps the HLO small (critical for 512-device dry-run
-compiles) and makes remat policies uniform.
+A model is ``embed -> lead -> scan(groups) -> final_norm -> lm_head`` where
+one group is one repetition of ``cfg.pattern`` (e.g. Gemma-2: (local, global)
+x 13; Zamba2: (mamba x 6 + shared attn at position 0) x 9; RWKV6: (rwkv,) x
+24).  Scanning over groups keeps the HLO small (critical for 512-device
+dry-run compiles) and makes remat policies uniform.  ``cfg.lead`` blocks
+(DeepSeek-V3's leading dense layers) run once each before the scan, from
+``params["lead"]["layer<i>"]``.
 
 All block params for one pattern position are stacked along a leading G axis.
+A decode cache is a tuple with one entry per leading block, then one per
+pattern position (``layer_specs``); every entry's leaves lead with their
+layer axis (1 for a leading block, G in the scan).
 """
 from __future__ import annotations
 
@@ -33,9 +38,18 @@ from repro.models.moe import MoEConfig, init_moe, moe_ffn
 @dataclasses.dataclass(frozen=True)
 class BlockSpec:
     kind: str = "attn"              # attn | mamba2 | rwkv6
-    attn_type: str = "global"       # global | local
+    attn_type: str = "global"       # global | local | mla
     mlp: str = "swiglu"             # swiglu | geglu | gelu | moe | rwkv_cm | none
     shared_attn: bool = False       # prepend the shared attention block (zamba2)
+
+
+@dataclasses.dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention sizes (DeepSeek-V2/V3)."""
+    kv_lora_rank: int               # latent c_kv width
+    qk_nope_dim: int                # per-head key/query dims without RoPE
+    qk_rope_dim: int                # RoPE dims, one key shared by all heads
+    v_head_dim: int
 
 
 @dataclasses.dataclass(frozen=True)
@@ -62,6 +76,9 @@ class ModelConfig:
     tie_embeddings: bool = False
     embed_scale: bool = False
     moe: Optional[MoEConfig] = None
+    mla: Optional[MLAConfig] = None  # sizes of attn_type="mla" blocks
+    lead: tuple[BlockSpec, ...] = ()  # blocks before the scanned groups
+    norm_eps: float = 1e-6          # every RMSNorm's eps
     # ssm / rwkv
     d_inner: int = 0
     d_state: int = 0
@@ -90,8 +107,9 @@ class ModelConfig:
 
     @property
     def n_groups(self) -> int:
-        assert self.n_layers % len(self.pattern) == 0
-        return self.n_layers // len(self.pattern)
+        body = self.n_layers - len(self.lead)
+        assert body % len(self.pattern) == 0
+        return body // len(self.pattern)
 
     @property
     def cdtype(self):
@@ -110,7 +128,11 @@ def _init_block(key, cfg: ModelConfig, spec: BlockSpec) -> dict:
     ks = jax.random.split(key, 8)
     p: dict[str, Any] = {}
     dt = cfg.pdtype
-    if spec.kind == "attn":
+    if spec.kind == "attn" and spec.attn_type == "mla":
+        p["ln1"] = init_norm(cfg.d_model, dt)
+        p["attn"] = attn_lib.init_mla(ks[0], cfg.d_model, cfg.n_heads,
+                                      cfg.mla, dt)
+    elif spec.kind == "attn":
         p["ln1"] = init_norm(cfg.d_model, dt)
         p["attn"] = attn_lib.init_attention(ks[0], cfg.d_model, cfg.n_heads,
                                             cfg.n_kv, cfg.head_dim,
@@ -155,14 +177,24 @@ def init_group(gkeys: jax.Array, cfg: ModelConfig) -> tuple:
                  for pi, spec in enumerate(cfg.pattern))
 
 
+def layer_specs(cfg) -> tuple:
+    """The block spec of each decode-cache entry: the leading blocks, then
+    the pattern positions."""
+    return tuple(getattr(cfg, "lead", ())) + tuple(cfg.pattern)
+
+
 def init_outer(keys: jax.Array, cfg: ModelConfig) -> dict:
-    """Everything outside the scanned blocks: embedding, final norm, LM head
-    and the shared attention block, from the tail of ``init_params``'
-    split."""
+    """Everything outside the scanned blocks: embedding, final norm, LM head,
+    the shared attention block and the leading blocks (``init_params``'
+    keys after the scanned groups' and at its tail)."""
     params = {
         "embed": init_embedding(keys[-1], cfg.vocab, cfg.d_model, cfg.pdtype),
         "final_norm": init_norm(cfg.d_model, cfg.pdtype),
     }
+    if cfg.lead:
+        first = cfg.n_groups * len(cfg.pattern)
+        params["lead"] = {f"layer{i}": _init_block(keys[first + i], cfg, spec)
+                          for i, spec in enumerate(cfg.lead)}
     if not cfg.tie_embeddings:
         params["lm_head"] = init_linear(keys[-2], cfg.d_model, cfg.vocab,
                                         dtype=cfg.pdtype)
@@ -195,7 +227,26 @@ def init_params(key, cfg: ModelConfig) -> dict:
 def _norm(pnorm, x, cfg: ModelConfig):
     if cfg.norm == "layernorm":
         return layer_norm(pnorm, x)
-    return rms_norm(pnorm, x, zero_centered=cfg.gemma_norms)
+    return rms_norm(pnorm, x, cfg.norm_eps, zero_centered=cfg.gemma_norms)
+
+
+def _mla_kw(cfg: ModelConfig) -> dict:
+    return dict(n_heads=cfg.n_heads, mla=cfg.mla, rope_theta=cfg.rope_theta,
+                norm_eps=cfg.norm_eps, quant=_infer_quant(cfg),
+                compute_dtype=cfg.cdtype)
+
+
+def _moe(bp: dict, cfg: ModelConfig, h: jax.Array, decode: bool = False):
+    """The block's MoE over its normed input ``h`` [B, S, d]: (y, aux loss,
+    int32[3] counters).  A decode step's global-dispatch MoE takes a
+    capacity from the batch size alone."""
+    det_cap = None
+    if decode and cfg.moe.dispatch == "global":
+        det_cap = max(1, int(h.shape[0] * cfg.moe.top_k / cfg.moe.n_experts
+                             * cfg.moe.capacity_factor) + 1)
+    return moe_ffn(bp["moe"], h, cfg.moe, quant=_infer_quant(cfg),
+                   compute_dtype=cfg.cdtype, deterministic_capacity=det_cap,
+                   with_stats=True)
 
 
 def _block_fwd(bp: dict, spec: BlockSpec, cfg: ModelConfig, x: jax.Array,
@@ -213,7 +264,10 @@ def _block_fwd(bp: dict, spec: BlockSpec, cfg: ModelConfig, x: jax.Array,
         h = _norm(shared_p["mlp_ln"], x, cfg)
         x = x + mlp(shared_p["mlp"], h, "swiglu", _infer_quant(cfg), cd)
     h = _norm(bp["ln1"], x, cfg)
-    if spec.kind == "attn":
+    if spec.kind == "attn" and spec.attn_type == "mla":
+        x = x + attn_lib.mla_attention(bp["attn"], h, positions,
+                                       **_mla_kw(cfg))
+    elif spec.kind == "attn":
         window = cfg.window if spec.attn_type == "local" else None
         y = attn_lib.attention(
             bp["attn"], h, positions, n_heads=cfg.n_heads, n_kv=cfg.n_kv,
@@ -235,8 +289,7 @@ def _block_fwd(bp: dict, spec: BlockSpec, cfg: ModelConfig, x: jax.Array,
                                       quant=_infer_quant(cfg), compute_dtype=cd)
     if spec.mlp == "moe":
         h = _norm(bp["ln2"], x, cfg)
-        y, aux = moe_ffn(bp["moe"], h, cfg.moe, quant=_infer_quant(cfg),
-                         compute_dtype=cd)
+        y, aux, _ = _moe(bp, cfg, h)
         x = x + y
         if aux_acc is not None:
             aux_acc = aux_acc + aux
@@ -315,6 +368,10 @@ def forward(params: dict, cfg: ModelConfig, tokens: jax.Array,
     positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
     x = constrain(x, "batch", "seq", None)
     shared_p = params.get("shared_attn")
+    aux = jnp.zeros((), jnp.float32)
+    for i, spec in enumerate(cfg.lead):
+        x, aux = _block_fwd(params["lead"][f"layer{i}"], spec, cfg, x,
+                            positions, shared_p, mrope_positions, aux)
 
     def group_body(carry, group_params):
         x, aux = carry
@@ -327,8 +384,8 @@ def forward(params: dict, cfg: ModelConfig, tokens: jax.Array,
     if cfg.remat != "none":
         body = jax.checkpoint(group_body, policy=_remat_policy(cfg),
                               prevent_cse=False)
-    (x, aux), _ = maybe_scan(body, (x, jnp.zeros((), jnp.float32)),
-                             params["blocks"], cfg.unroll_groups)
+    (x, aux), _ = maybe_scan(body, (x, aux), params["blocks"],
+                             cfg.unroll_groups)
     x = _norm(params["final_norm"], x, cfg)
     logits = _lm_head(params, cfg, x.astype(cd))
     logits = constrain(logits, "batch", "seq", "vocab")
@@ -392,7 +449,12 @@ def _block_prefill(bp, cache_tmpl, spec: BlockSpec, cfg: ModelConfig,
         x = x + mlp(shared_p["mlp"], h, "swiglu", q, cd)
         cache["shared_k"], cache["shared_v"] = sk.astype(cd), sv.astype(cd)
     h = _norm(bp["ln1"], x, cfg)
-    if spec.kind == "attn":
+    if spec.kind == "attn" and spec.attn_type == "mla":
+        y, latent = attn_lib.mla_attention(bp["attn"], h, positions,
+                                           return_cache=True, **_mla_kw(cfg))
+        x = x + y
+        cache.update(latent)
+    elif spec.kind == "attn":
         window = cfg.window if spec.attn_type == "local" else None
         y, (k, v) = attn_lib.attention(
             bp["attn"], h, positions, n_heads=cfg.n_heads, n_kv=cfg.n_kv,
@@ -424,7 +486,7 @@ def _block_prefill(bp, cache_tmpl, spec: BlockSpec, cfg: ModelConfig,
         cache["S"], cache["xt"] = Sf, xlast.astype(cd)
     if spec.mlp == "moe":
         h = _norm(bp["ln2"], x, cfg)
-        y, _ = moe_ffn(bp["moe"], h, cfg.moe, quant=q, compute_dtype=cd)
+        y, _, _ = _moe(bp, cfg, h)
         x = x + y
     elif spec.mlp == "rwkv_cm":
         h = _norm(bp["ln2"], x, cfg)
@@ -471,6 +533,12 @@ def prefill(params: dict, cfg: ModelConfig, tokens: jax.Array,
     positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
     x = constrain(x, "batch", "seq", None)
     shared_p = params.get("shared_attn")
+    lead = []
+    for i, spec in enumerate(cfg.lead):
+        x, c = _block_prefill(params["lead"][f"layer{i}"], None, spec, cfg, x,
+                              positions, shared_p, mrope_positions,
+                              full_kv=full_kv)
+        lead.append(jax.tree_util.tree_map(lambda a: a[None], c))
 
     def group_body(x, group_params):
         caches = []
@@ -485,6 +553,7 @@ def prefill(params: dict, cfg: ModelConfig, tokens: jax.Array,
         body = jax.checkpoint(group_body, policy=_remat_policy(cfg),
                               prevent_cse=False)
     x, cache = maybe_scan(body, x, params["blocks"], cfg.unroll_groups)
+    cache = tuple(lead) + tuple(cache)
     x = _norm(params["final_norm"], x, cfg)
     if length is None:
         xl = x[:, -1]
@@ -499,49 +568,58 @@ def prefill(params: dict, cfg: ModelConfig, tokens: jax.Array,
     return logits, cache
 
 def init_cache(cfg: ModelConfig, batch: int, max_len: int) -> tuple:
-    """Per-pattern-position stacked caches (leading G dim)."""
-    G = cfg.n_groups
-    caches = []
+    """Decode caches, one entry per ``layer_specs`` position: a leading
+    block's leaves lead with a layer axis of 1, a pattern position's with
+    the G groups it is stacked over."""
+    return tuple(_block_cache(cfg, spec, 1 if i < len(cfg.lead)
+                              else cfg.n_groups, batch, max_len)
+                 for i, spec in enumerate(layer_specs(cfg)))
+
+
+def _block_cache(cfg: ModelConfig, spec: BlockSpec, G: int, batch: int,
+                 max_len: int) -> dict:
     cd = cfg.cdtype
-    for spec in cfg.pattern:
-        if spec.kind == "attn":
-            is_local = spec.attn_type == "local" and cfg.window
-            T = min(max_len, cfg.window) if is_local else max_len
-            if cfg.kv_quant == "int8" and not is_local:
-                c = {"k": jnp.zeros((G, batch, T, cfg.n_kv, cfg.head_dim),
-                                    jnp.int8),
-                     "v": jnp.zeros((G, batch, T, cfg.n_kv, cfg.head_dim),
-                                    jnp.int8),
-                     "k_scale": jnp.zeros((G, batch, T, cfg.n_kv),
-                                          jnp.float32),
-                     "v_scale": jnp.zeros((G, batch, T, cfg.n_kv),
-                                          jnp.float32)}
-            else:
-                c = {"k": jnp.zeros((G, batch, T, cfg.n_kv, cfg.head_dim), cd),
-                     "v": jnp.zeros((G, batch, T, cfg.n_kv, cfg.head_dim), cd)}
-            if spec.shared_attn:
-                c["shared_k"] = jnp.zeros((G, batch, max_len, cfg.n_kv,
-                                           cfg.head_dim), cd)
-                c["shared_v"] = jnp.zeros((G, batch, max_len, cfg.n_kv,
-                                           cfg.head_dim), cd)
-        elif spec.kind == "mamba2":
-            P = cfg.d_inner // cfg.ssm_heads
-            c = {"h": jnp.zeros((G, batch, cfg.ssm_heads, cfg.d_state, P),
-                                jnp.float32),
-                 "conv": jnp.zeros((G, batch, 3, cfg.d_inner + 2 * cfg.d_state),
-                                   cd)}
-            if spec.shared_attn:
-                c["shared_k"] = jnp.zeros((G, batch, max_len, cfg.n_kv,
-                                           cfg.head_dim), cd)
-                c["shared_v"] = jnp.zeros((G, batch, max_len, cfg.n_kv,
-                                           cfg.head_dim), cd)
-        elif spec.kind == "rwkv6":
-            K = cfg.d_model // cfg.rwkv_heads
-            c = {"S": jnp.zeros((G, batch, cfg.rwkv_heads, K, K), jnp.float32),
-                 "xt": jnp.zeros((G, batch, 1, cfg.d_model), cd),
-                 "xc": jnp.zeros((G, batch, 1, cfg.d_model), cd)}
-        caches.append(c)
-    return tuple(caches)
+    if spec.kind == "attn" and spec.attn_type == "mla":
+        # the latent rows: normed c_kv and the roped shared key
+        c = {"c_kv": jnp.zeros((G, batch, max_len, cfg.mla.kv_lora_rank), cd),
+             "k_pe": jnp.zeros((G, batch, max_len, cfg.mla.qk_rope_dim), cd)}
+    elif spec.kind == "attn":
+        is_local = spec.attn_type == "local" and cfg.window
+        T = min(max_len, cfg.window) if is_local else max_len
+        if cfg.kv_quant == "int8" and not is_local:
+            c = {"k": jnp.zeros((G, batch, T, cfg.n_kv, cfg.head_dim),
+                                jnp.int8),
+                 "v": jnp.zeros((G, batch, T, cfg.n_kv, cfg.head_dim),
+                                jnp.int8),
+                 "k_scale": jnp.zeros((G, batch, T, cfg.n_kv),
+                                      jnp.float32),
+                 "v_scale": jnp.zeros((G, batch, T, cfg.n_kv),
+                                      jnp.float32)}
+        else:
+            c = {"k": jnp.zeros((G, batch, T, cfg.n_kv, cfg.head_dim), cd),
+                 "v": jnp.zeros((G, batch, T, cfg.n_kv, cfg.head_dim), cd)}
+        if spec.shared_attn:
+            c["shared_k"] = jnp.zeros((G, batch, max_len, cfg.n_kv,
+                                       cfg.head_dim), cd)
+            c["shared_v"] = jnp.zeros((G, batch, max_len, cfg.n_kv,
+                                       cfg.head_dim), cd)
+    elif spec.kind == "mamba2":
+        P = cfg.d_inner // cfg.ssm_heads
+        c = {"h": jnp.zeros((G, batch, cfg.ssm_heads, cfg.d_state, P),
+                            jnp.float32),
+             "conv": jnp.zeros((G, batch, 3, cfg.d_inner + 2 * cfg.d_state),
+                               cd)}
+        if spec.shared_attn:
+            c["shared_k"] = jnp.zeros((G, batch, max_len, cfg.n_kv,
+                                       cfg.head_dim), cd)
+            c["shared_v"] = jnp.zeros((G, batch, max_len, cfg.n_kv,
+                                       cfg.head_dim), cd)
+    elif spec.kind == "rwkv6":
+        K = cfg.d_model // cfg.rwkv_heads
+        c = {"S": jnp.zeros((G, batch, cfg.rwkv_heads, K, K), jnp.float32),
+             "xt": jnp.zeros((G, batch, 1, cfg.d_model), cd),
+             "xc": jnp.zeros((G, batch, 1, cfg.d_model), cd)}
+    return c
 
 
 def init_paged_cache(cfg: ModelConfig, batch: int, max_len: int,
@@ -552,13 +630,17 @@ def init_paged_cache(cfg: ModelConfig, batch: int, max_len: int,
     in the scheduler's page tables, not here.  SWA ring layers use the same
     pool shape (their pages are addressed through the ring table).
     Recurrent (mamba2 / rwkv6) states have no sequence axis and stay dense
-    per-slot buffers of ``batch`` rows."""
-    G = cfg.n_groups
+    per-slot buffers of ``batch`` rows.  MLA's latent cache has no paged
+    form."""
+    if any(s.attn_type == "mla" for s in layer_specs(cfg)):
+        raise NotImplementedError("the latent (MLA) cache has no paged form: "
+                                  "serve MLA models with paged=False")
     sds = jax.eval_shape(lambda: init_cache(cfg, batch, max_len))
     caches = []
-    for spec, c in zip(cfg.pattern, sds):
+    for c in sds:
         out = {}
         for key, leaf in c.items():
+            G = leaf.shape[0]
             if key in ("k", "v", "shared_k", "shared_v"):
                 out[key] = jnp.zeros(
                     (G, num_pages, page_size) + leaf.shape[3:], leaf.dtype)
@@ -592,7 +674,16 @@ def _block_decode(bp: dict, cache: dict, spec: BlockSpec, cfg: ModelConfig,
         x = x + mlp(shared_p["mlp"], h, "swiglu", q, cd)
         cache = {**cache, "shared_k": ck, "shared_v": cv}
     h = _norm(bp["ln1"], x, cfg)
-    if spec.kind == "attn":
+    if spec.kind == "attn" and spec.attn_type == "mla":
+        if tables is not None:
+            raise NotImplementedError("the latent (MLA) cache has no paged "
+                                      "form")
+        y, latent = attn_lib.mla_decode_attention(
+            bp["attn"], h, {k: cache[k] for k in ("c_kv", "k_pe")}, pos,
+            **_mla_kw(cfg))
+        x = x + y
+        cache = {**cache, **latent}
+    elif spec.kind == "attn":
         window = cfg.window if spec.attn_type == "local" else None
         is_local = spec.attn_type == "local" and cfg.window is not None
         if tables is not None:
@@ -645,16 +736,12 @@ def _block_decode(bp: dict, cache: dict, spec: BlockSpec, cfg: ModelConfig,
 
 
 def _finish_block_decode(bp, cache, spec, cfg, x, q, cd):
-    """MLP / MoE / channel-mix tail of a decode block."""
+    """MLP / MoE / channel-mix tail of a decode block: (x, cache, int32[3]
+    MoE counters, zeros without MoE)."""
+    counts = jnp.zeros((3,), jnp.int32)
     if spec.mlp == "moe":
         h = _norm(bp["ln2"], x, cfg)
-        det_cap = None
-        if cfg.moe.dispatch == "global":
-            det_cap = max(1, int(x.shape[0] * cfg.moe.top_k
-                                 / cfg.moe.n_experts
-                                 * cfg.moe.capacity_factor) + 1)
-        y, _ = moe_ffn(bp["moe"], h, cfg.moe, quant=q, compute_dtype=cd,
-                       deterministic_capacity=det_cap)
+        y, _, counts = _moe(bp, cfg, h, decode=True)
         x = x + y
     elif spec.mlp == "rwkv_cm":
         h = _norm(bp["ln2"], x, cfg)
@@ -667,7 +754,7 @@ def _finish_block_decode(bp, cache, spec, cfg, x, q, cd):
         if cfg.gemma_norms:
             y = _norm(bp["post_mlp_ln"], y, cfg)
         x = x + y
-    return x, cache
+    return x, cache, counts
 
 
 def _block_verify(bp: dict, cache: dict, spec: BlockSpec, cfg: ModelConfig,
@@ -679,7 +766,7 @@ def _block_verify(bp: dict, cache: dict, spec: BlockSpec, cfg: ModelConfig,
     shared-attention patterns at construction)."""
     cd = cfg.cdtype
     q = _infer_quant(cfg)
-    if (spec.kind != "attn" or spec.shared_attn
+    if (spec.kind != "attn" or spec.shared_attn or spec.attn_type == "mla"
             or (spec.attn_type == "local" and cfg.window)
             or spec.mlp in ("moe", "rwkv_cm") or "k_scale" in cache):
         raise ValueError(
@@ -698,7 +785,7 @@ def _block_verify(bp: dict, cache: dict, spec: BlockSpec, cfg: ModelConfig,
         y = _norm(bp["post_attn_ln"], y, cfg)
     x = x + y
     cache = {**cache, "k": ck, "v": cv}
-    return _finish_block_decode(bp, cache, spec, cfg, x, q, cd)
+    return _finish_block_decode(bp, cache, spec, cfg, x, q, cd)[:2]
 
 
 def verify_step(params: dict, cfg: ModelConfig, tokens: jax.Array,
@@ -714,6 +801,9 @@ def verify_step(params: dict, cfg: ModelConfig, tokens: jax.Array,
     before attention and the causal mask hides keys past ``pos + i`` from
     query i.  The batched [B*S] matmuls are where the verify step beats S
     sequential target steps."""
+    if cfg.lead:
+        raise ValueError("verify_step runs the scanned groups only: models "
+                         "with leading blocks cannot run speculative rounds")
     cd = cfg.cdtype
     x = params["embed"]["emb"].astype(cd)[tokens]               # [B,S,d]
     if cfg.embed_scale:
@@ -738,37 +828,56 @@ def verify_step(params: dict, cfg: ModelConfig, tokens: jax.Array,
 
 
 def decode_step(params: dict, cfg: ModelConfig, token: jax.Array,
-                cache: tuple, pos: jax.Array,
-                tables=None) -> tuple[jax.Array, tuple]:
+                cache: tuple, pos: jax.Array, tables=None,
+                counters: bool = False) -> tuple:
     """One token for the whole batch. token: [B] int32; pos: scalar int32 or
     per-sequence [B] int32 (continuous batching — each slot at its own depth;
     negative marks a free slot whose keys stay masked).
 
     ``tables`` (paged serving): a ``(full_table [B, E], ring_table [B, Er])``
     pair of int32 page tables — the attention cache leaves are then shared
-    page pools instead of per-slot dense buffers (see ``serve.paged``)."""
+    page pools instead of per-slot dense buffers (see ``serve.paged``).
+
+    Returns (logits, cache), and with ``counters`` also the MoE layers'
+    int32[3] counters summed over layers (experts that got a token, pairs
+    computed, pairs dropped)."""
     cd = cfg.cdtype
-    x = params["embed"]["emb"].astype(cd)[token][:, None, :]    # [B,1,d]
+    # gather the rows, then cast: no compute-dtype copy of the whole table
+    x = params["embed"]["emb"][token].astype(cd)[:, None, :]    # [B,1,d]
     if cfg.embed_scale:
         x = x * jnp.asarray(math.sqrt(cfg.d_model), cd)
     shared_p = params.get("shared_attn")
+    counts = jnp.zeros((3,), jnp.int32)
+    n_lead = len(cfg.lead)
+    lead = []
+    for i, spec in enumerate(cfg.lead):
+        c = jax.tree_util.tree_map(lambda a: a[0], cache[i])
+        x, c, n = _block_decode(params["lead"][f"layer{i}"], c, spec, cfg, x,
+                                pos, shared_p, tables=tables)
+        counts = counts + n
+        lead.append(jax.tree_util.tree_map(lambda a: a[None], c))
 
     # scan over groups per pattern position jointly (tables are
     # scan-invariant: every group indexes the same per-slot page rows)
     def group_body(carry, scanned):
-        x, = carry
+        x, counts = carry
         gp, gc = scanned                 # tuple(params), tuple(cache)
         out_caches = []
         for bp, c, spec in zip(gp, gc, cfg.pattern):
-            x, c = _block_decode(bp, c, spec, cfg, x, pos, shared_p,
-                                 tables=tables)
+            x, c, n = _block_decode(bp, c, spec, cfg, x, pos, shared_p,
+                                    tables=tables)
+            counts = counts + n
             out_caches.append(c)
-        return (x,), tuple(out_caches)
+        return (x, counts), tuple(out_caches)
 
-    (x,), cache = maybe_scan(group_body, (x,),
-                             (params["blocks"], cache), cfg.unroll_groups)
+    (x, counts), body = maybe_scan(group_body, (x, counts),
+                                   (params["blocks"], tuple(cache[n_lead:])),
+                                   cfg.unroll_groups)
+    cache = tuple(lead) + tuple(body)
     x = _norm(params["final_norm"], x, cfg)
     logits = _lm_head(params, cfg, x[:, 0].astype(cd)).astype(jnp.float32)
     if cfg.final_softcap:
         logits = softcap(logits, cfg.final_softcap)
+    if counters:
+        return logits, cache, counts
     return logits, cache

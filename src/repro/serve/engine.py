@@ -249,7 +249,8 @@ def _scatter_pages(pool: jax.Array, table: jax.Array, piece: jax.Array,
         vals.astype(pool.dtype))
 
 
-_FLOAT_KV_KEYS = ("k", "v", "shared_k", "shared_v", "k_scale", "v_scale")
+_FLOAT_KV_KEYS = ("k", "v", "shared_k", "shared_v", "k_scale", "v_scale",
+                  "c_kv", "k_pe")
 
 
 def _cache_finite(cache) -> jax.Array:
@@ -334,6 +335,11 @@ class Engine:
                     "paged serving drives decoder-only LMs through the "
                     "scheduler; enc-dec decode supports page tables at the "
                     "encdec.decode_step level only")
+            if any(spec.attn_type == "mla"
+                   for spec in transformer.layer_specs(cfg)):
+                raise NotImplementedError(
+                    "paged serving has no latent (MLA) cache: serve MLA "
+                    "models with paged=False")
             paged_layout(cfg, scfg)          # raises on bad page geometry
             if scfg.num_pages and scfg.num_pages // n_page_shards < 2:
                 raise ValueError(
@@ -493,8 +499,8 @@ class Engine:
 
     def _kv_leaf_bytes(self, batch: int) -> int:
         from repro.launch.specs import (KV_CACHE_LEAVES, KV_SCALE_LEAVES,
-                                        _leaf_key)
-        names = KV_CACHE_LEAVES | KV_SCALE_LEAVES
+                                        LATENT_CACHE_LEAVES, _leaf_key)
+        names = KV_CACHE_LEAVES | KV_SCALE_LEAVES | LATENT_CACHE_LEAVES
         total = 0
         for path, leaf in jax.tree_util.tree_flatten_with_path(
                 self._cache_sds(batch))[0]:
@@ -513,7 +519,8 @@ class Engine:
 
     def kv_cache_bytes(self, batch: int) -> int:
         """KV memory figure for the serving bench: bytes of the attention
-        KV leaves (K/V + int8-KV scales + shared-attention K/V).
+        KV leaves (K/V + int8-KV scales + shared-attention K/V + MLA's
+        latent rows).
 
         Dense engines report ``max_len`` *capacity* — every slot owns a
         worst-case buffer.  Paged engines report *allocated residency*: the
@@ -581,9 +588,13 @@ class Engine:
         if paged:
             table, ring_t, start = paged
         out = []
-        for spec, live, part in zip(cfg.pattern, cache, pcache):
+        for spec, live, part in zip(transformer.layer_specs(cfg), cache,
+                                    pcache):
             c = dict(live)
-            if spec.kind == "attn":
+            if spec.attn_type == "mla":           # latent rows, dense only
+                for key in ("c_kv", "k_pe"):
+                    c[key] = _write_rows(live[key], part[key], mask)
+            elif spec.kind == "attn":
                 is_local = spec.attn_type == "local" and bool(cfg.window)
                 if paged:
                     Pb = part["k"].shape[2]
@@ -745,12 +756,15 @@ class Engine:
         wide and only the first ``n_valid[b]`` columns of row b are real.
 
         Returns (cache, tok, pos, done, tok0, done0, tokens [B, W],
-        dones [B, W], ok [B], n_valid [B]) with W = chunk (or draft_k+1
-        under ``spec``) — tok0/done0 are per-slot first tokens /
+        dones [B, W], ok [B], n_valid [B], moe int32[3]) with W = chunk (or
+        draft_k+1 under ``spec``) — tok0/done0 are per-slot first tokens /
         immediately-finished flags, meaningful at rows whose ``first``
         entry fired this round; ok is the per-slot finite-logits guard over
         the whole round; n_valid counts the tokens each row actually
-        advanced (always W on non-speculative rounds).  Compiles once per
+        advanced (always W on non-speculative rounds); moe sums the decode
+        lane's MoE counters over its forwards and layers (experts that got
+        a token, (token, expert) pairs computed, pairs dropped; zeros
+        without MoE).  Compiles once per
         (has-entries, chunk, greedy, spec).
         """
         if self.is_encdec:
@@ -797,6 +811,7 @@ class Engine:
             tables = paged if paged else None
             ok = jnp.ones(tok.shape, bool)
             tok0, done0 = tok, done
+            moe = jnp.zeros((3,), jnp.int32)
 
             def sample(logits, key_i):
                 with jax.named_scope("sample"):
@@ -907,9 +922,11 @@ class Engine:
                                     & (cols < n_valid[:, None])).T
             else:
                 def step(carry, j):
-                    cache, tok, pos, done, ok = carry
-                    logits, cache = mod.decode_step(params, cfg, tok, cache,
-                                                    pos, tables=tables)
+                    cache, tok, pos, done, ok, moe = carry
+                    logits, cache, n = mod.decode_step(
+                        params, cfg, tok, cache, pos, tables=tables,
+                        counters=True)
+                    moe = moe + n
                     # finite-logits guard: rows already done (or free)
                     # before this step never sampled these logits — ignore
                     ok = ok & (jnp.isfinite(logits).all(axis=-1) | done)
@@ -918,11 +935,11 @@ class Engine:
                     nxt = jnp.where(done, tok, nxt)
                     pos = jnp.where(done, pos, pos + 1)
                     done = done | ((nxt == eos) & (eos >= 0))
-                    return (cache, nxt, pos, done, ok), (nxt, done)
+                    return (cache, nxt, pos, done, ok, moe), (nxt, done)
 
                 with jax.named_scope("decode_lane"):
-                    (cache, tok, pos, done, ok), (toks, dones) = \
-                        jax.lax.scan(step, (cache, tok, pos, done, ok),
+                    (cache, tok, pos, done, ok, moe), (toks, dones) = \
+                        jax.lax.scan(step, (cache, tok, pos, done, ok, moe),
                                      jnp.arange(chunk, dtype=jnp.int32))
                 n_valid = jnp.full(tok.shape, chunk, jnp.int32)
             # cache-finiteness guard: quantized (integer-code) matmul paths
@@ -942,8 +959,11 @@ class Engine:
                     cache_ok = jax.lax.pmin(
                         cache_ok.astype(jnp.int32), axis).astype(bool)
             ok = ok & cache_ok
+            axis = tp_lib.data_axis()
+            if axis is not None:             # every data shard's own slots
+                moe = jax.lax.psum(moe, axis)
             return (cache, tok, pos, done, tok0, done0, toks.T, dones.T, ok,
-                    n_valid)
+                    n_valid, moe)
 
         return run
 
@@ -961,9 +981,9 @@ class Engine:
                     buf, cache[k], 0, axis=2)
             return grown
         out = []
-        for spec, c in zip(cfg.pattern, cache):
+        for spec, c in zip(transformer.layer_specs(cfg), cache):
             c = dict(c)
-            for key in ("k", "v", "shared_k", "shared_v"):
+            for key in ("k", "v", "shared_k", "shared_v", "c_kv", "k_pe"):
                 if key not in c:
                     continue
                 T = c[key].shape[2]

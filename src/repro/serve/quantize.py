@@ -21,6 +21,10 @@ structure* (same idiom as the dist.tp ``tp_*`` markers) — ``jit`` sees the
 bit width without tracing on values.  ``models.layers.linear`` dispatches
 on the presence of ``w_q`` and on its rank (3D = tmac).
 Embedding and lm_head follow the paper's first/last-layer rule (8-bit).
+MLA's ``wkv_b`` and the MoE router stay float: the absorbed MLA decode
+contracts ``wkv_b`` with the query and the latent readout, never with a
+quantized ``c_kv``, so only a float ``wkv_b`` keeps it the prefill's
+function.
 """
 from __future__ import annotations
 
@@ -34,7 +38,7 @@ from repro.core.lut import pack_int4
 
 # projection leaves eligible for low-bit quantization (trailing ['w'])
 _INNER_W = re.compile(
-    r"\['(wq|wk|wv|wo|wi|wg|wr|in_proj|out_proj)'\]\['w'\]$")
+    r"\['(wq|wk|wv|wo|wi|wg|wr|wkv_a|in_proj|out_proj)'\]\['w'\]$")
 _MOE_W = re.compile(r"\['moe'\]\['w[igo]'\]$")
 _HEAD_W = re.compile(r"\['lm_head'\]\['w'\]$")
 

@@ -96,6 +96,10 @@ from repro.serve.request import Request, RequestStatus
 
 _UNSET = object()
 
+# stats keys newer than some saved snapshots (restored as 0)
+_LATER_STATS = ("spec_rounds", "spec_drafted", "spec_accepted",
+                "experts_hit", "moe_pairs", "dropped_pairs")
+
 # host spans of a round, on the profiler's clock (one TraceMe check each when
 # no trace is being taken): serve.step holds serve.admin, serve.assemble,
 # serve.dispatch, serve.wait and serve.deliver
@@ -188,7 +192,11 @@ class Scheduler:
                       "preemptions": 0, "shed": 0, "timed_out": 0,
                       "recoveries": 0, "dispatch_retries": 0, "failed": 0,
                       "spec_rounds": 0, "spec_drafted": 0,
-                      "spec_accepted": 0}
+                      "spec_accepted": 0,
+                      # MoE decode-lane counters, summed over forwards and
+                      # layers: experts that got a token, (token, expert)
+                      # pairs computed, pairs dropped by capacity
+                      "experts_hit": 0, "moe_pairs": 0, "dropped_pairs": 0}
 
     # -- paged helpers -------------------------------------------------------
 
@@ -601,7 +609,7 @@ class Scheduler:
         if snap["pool"] is not None:
             eng.pool.load_state(snap["pool"])
         self.stats = dict(snap["stats"])
-        for k in ("spec_rounds", "spec_drafted", "spec_accepted"):
+        for k in _LATER_STATS:
             self.stats.setdefault(k, 0)
         for r in self._submit_log:       # post-snapshot submissions survive
             r.status = RequestStatus.QUEUED
@@ -717,7 +725,7 @@ class Scheduler:
         self._target = list(s.get("target", [0] * self.n_slots))
         self._submit_count = s["submit_count"]
         self.stats = dict(s["stats"])
-        for k in ("spec_rounds", "spec_drafted", "spec_accepted"):
+        for k in _LATER_STATS:
             self.stats.setdefault(k, 0)
         if s["pool"] is not None:
             self.engine.pool.load_state(s["pool"])
@@ -893,7 +901,7 @@ class Scheduler:
             # streams
             self._step += rnd.C + (2 * scfg.draft_k + 1 if rnd.spec
                                    else self.chunk)
-        _, _, toks, _, ok, _ = outs
+        _, _, toks, _, ok, _, _ = outs
         with _span("serve.wait"):
             # the one place the host waits on the round's program: its
             # outputs become ready together, so later reads find them
@@ -976,11 +984,11 @@ class Scheduler:
 
     def _dispatch_round(self, rnd: _Round) -> tuple:
         """Enqueue the round's one program; returns its outputs (tok0,
-        done0, toks, dones, ok, n_valid) without waiting on them."""
+        done0, toks, dones, ok, n_valid, moe) without waiting on them."""
         fresh = rnd.fresh
         try:
             (self.cache, self.tok, self.pos, self.done, tok0, done0, toks,
-             dones, ok, n_valid) = self.engine.step(
+             dones, ok, n_valid, moe) = self.engine.step(
                 self.cache, rnd.entries, self.tok, self.pos, self.done,
                 self.eos, self.temperature, self.top_k, self.top_p,
                 self._step, self.chunk, greedy=rnd.greedy, spec=rnd.spec)
@@ -1002,7 +1010,7 @@ class Scheduler:
                 # restore the free-slot sentinel the parks overwrote
                 self._free_on_device([slot for slot, _ in fresh])
             raise
-        return tok0, done0, toks, dones, ok, n_valid
+        return tok0, done0, toks, dones, ok, n_valid, moe
 
     def _deliver_round(self, rnd: _Round, outs: tuple, first_h: np.ndarray,
                        now) -> int:
@@ -1010,7 +1018,7 @@ class Scheduler:
         retire finished requests.  ``first_h`` is the host copy of the
         round's first read output: ``ok`` with guards on, else ``toks``."""
         entries, plan, fresh, completing, C, _, use_spec = rnd
-        tok0, done0, toks, dones, _, n_valid = outs
+        tok0, done0, toks, dones, _, n_valid, moe = outs
         scfg = self.engine.scfg
         if scfg.guards and not first_h.all():
             # poisoned logits never reach a streaming callback:
@@ -1039,6 +1047,9 @@ class Scheduler:
         dones_h = np.asarray(dones)
         tok0_h, done0_h = np.asarray(tok0), np.asarray(done0)
         nv_h = np.asarray(n_valid)
+        for name, n in zip(("experts_hit", "moe_pairs", "dropped_pairs"),
+                           np.asarray(moe).tolist()):
+            self.stats[name] += n
         if use_spec:
             # accept-rate telemetry: every live decode row drafted draft_k
             # tokens and committed n_valid-1 of them (the last committed

@@ -146,7 +146,8 @@ class ShardedEngine(Engine):
                      d, d,                # first tokens/dones [slots]
                      d, d,                # decode tokens/dones [slots, W]
                      d,                   # ok finite-logits guard
-                     d)                   # n_valid accepted-width [slots]
+                     d,                   # n_valid accepted-width [slots]
+                     P())                 # MoE counters, summed over data
         return self._shard_jit(self._make_step_impl(C, chunk, greedy, spec),
                                in_specs, out_specs)
 
